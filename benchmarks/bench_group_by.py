@@ -10,10 +10,13 @@ Two comparisons back the PR's perf claims:
    re-folding its subset.  Cold (fresh session) and warm (repeat on the
    same session) walls for both.
 2. **tree vs funnel merge** — ``merge_finalize`` over many per-block
-   partials on an 8-device mesh (subprocess with
-   ``--xla_force_host_platform_device_count=8``), psum-tree against the
-   forced single-device funnel.  Skipped gracefully (reported as 0) where
-   the subprocess is unavailable.
+   partials on a data mesh of this process's devices (up to 8), psum-tree
+   against the forced single-device funnel.  It runs in this process: a
+   child process could not reach a chip the parent already holds.  With
+   one device there is no tree to compare; the probe then says so and
+   reports 0 (``merge_partials`` 0), which the perf gate treats as an
+   unavailable optional probe.  On CPU,
+   ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives it 8.
 
 Artifact: ``BENCH_group_by.json`` via benchmarks/run.py (also in
 ``--smoke``; CI uploads it and the perf gate checks the headline
@@ -22,20 +25,19 @@ Artifact: ``BENCH_group_by.json`` via benchmarks/run.py (also in
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.grid import GridSession
+from repro.core.mapreduce import MapReduceEngine
 from repro.core.regions import HierarchicalSplitPolicy
 from repro.core.stats import MeanProgram, VarianceProgram
 from repro.core.table import ColumnSpec, make_mip_table
+from repro.utils import make_mesh
 
 N_REGIONS = 16
 ROWS_PER_REGION = 32
@@ -43,49 +45,6 @@ PAYLOAD = (16, 16)
 N_SITES = 8
 ETA = 8
 REPS = 10
-
-MERGE_SNIPPET = """
-import json, time
-import numpy as np
-import jax, jax.numpy as jnp
-from repro.core.mapreduce import MapReduceEngine
-from repro.core.stats import MeanProgram
-from repro.utils import make_mesh
-
-D, P, PAYLOAD, REPS = 8, %(n_partials)d, %(payload)s, %(reps)d
-assert jax.device_count() == D
-mesh = make_mesh((D,), ("data",))
-devices = list(np.asarray(mesh.devices).flat)
-program = MeanProgram()
-rng = np.random.default_rng(0)
-partials = []
-owners = []
-for i in range(P):
-    owner = i %% D
-    p = {"sum": jnp.asarray(rng.normal(size=PAYLOAD).astype(np.float32)),
-         "count": jnp.asarray(np.float32(4.0))}
-    partials.append(jax.device_put(p, devices[owner]))
-    owners.append(owner)
-
-def timed(eng, **kw):
-    eng.merge_finalize(program, partials, PAYLOAD, np.float32, **kw)  # compile
-    samples = []
-    for _ in range(REPS):
-        t0 = time.perf_counter()
-        jax.block_until_ready(
-            eng.merge_finalize(program, partials, PAYLOAD, np.float32, **kw))
-        samples.append(time.perf_counter() - t0)
-    return float(np.median(samples))
-
-tree_eng = MapReduceEngine(mesh)
-funnel_eng = MapReduceEngine(mesh, merge_strategy="funnel")
-tree_s = timed(tree_eng, owners=owners)
-funnel_s = timed(funnel_eng, owners=owners)
-assert tree_eng.merge_path_counts["tree"] > 0
-assert funnel_eng.merge_path_counts["funnel"] > 0
-print("MERGE_JSON " + json.dumps({"tree_s": tree_s, "funnel_s": funnel_s}))
-"""
-
 
 def _make_table(seed=0):
     rng = np.random.default_rng(seed)
@@ -153,27 +112,37 @@ def _loop_queries(s, sites):
     return out
 
 
-def _merge_bench():
-    """tree vs funnel merge on 8 forced host devices (subprocess)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(repo, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    snippet = MERGE_SNIPPET % {
-        "n_partials": 256, "payload": repr(PAYLOAD), "reps": 20}
-    try:
-        proc = subprocess.run([sys.executable, "-c", snippet],
-                              capture_output=True, text=True, env=env,
-                              timeout=300)
-    except (subprocess.SubprocessError, OSError):
+def _merge_bench(n_partials=256, reps=20):
+    """tree vs funnel merge over this process's devices (up to 8); ``{}``
+    with one device, where no tree reduce exists."""
+    D = min(8, jax.device_count())
+    if D < 2:
         return {}
-    if proc.returncode != 0:
-        return {}
-    for line in proc.stdout.splitlines():
-        if line.startswith("MERGE_JSON "):
-            return json.loads(line[len("MERGE_JSON "):])
-    return {}
+    mesh = make_mesh((D,), ("data",))
+    devices = list(np.asarray(mesh.devices).flat)
+    program = MeanProgram()
+    rng = np.random.default_rng(0)
+    partials, owners = [], []
+    for i in range(n_partials):
+        p = {"sum": jnp.asarray(rng.normal(size=PAYLOAD).astype(np.float32)),
+             "count": jnp.asarray(np.float32(4.0))}
+        partials.append(jax.device_put(p, devices[i % D]))
+        owners.append(i % D)
+
+    def timed(eng):
+        def merge():
+            return eng.merge_finalize(program, partials, PAYLOAD,
+                                      np.float32, owners=owners)
+        jax.block_until_ready(merge())           # compile
+        return _timed(merge, reps)
+
+    tree_eng = MapReduceEngine(mesh)
+    funnel_eng = MapReduceEngine(mesh, merge_strategy="funnel")
+    tree_s = timed(tree_eng)
+    funnel_s = timed(funnel_eng)
+    assert tree_eng.merge_path_counts["tree"] > 0
+    assert funnel_eng.merge_path_counts["funnel"] > 0
+    return {"tree_s": tree_s, "funnel_s": funnel_s, "devices": D}
 
 
 def run(verbose: bool = True):
@@ -255,6 +224,7 @@ def run(verbose: bool = True):
         "merge_funnel_s": funnel_s,
         "merge_tree_speedup": (funnel_s / tree_s) if tree_s > 0 else 0.0,
         "merge_partials": 256 if merge else 0,
+        "merge_devices": int(merge.get("devices", jax.device_count())),
     }
     if verbose:
         print(f"grouped one-pass: cold={grouped_cold_s*1e3:.1f}ms "
@@ -266,11 +236,12 @@ def run(verbose: bool = True):
               f"({grouped_speedup:.1f}x cold-data win, "
               f"{warm_speedup:.1f}x warm)")
         if merge:
-            print(f"merge @256 partials x 8 dev: tree={tree_s*1e3:.2f}ms "
-                  f"funnel={funnel_s*1e3:.2f}ms "
+            print(f"merge @256 partials x {merge['devices']} dev: "
+                  f"tree={tree_s*1e3:.2f}ms funnel={funnel_s*1e3:.2f}ms "
                   f"({out['merge_tree_speedup']:.2f}x)")
         else:
-            print("merge bench skipped (8-device subprocess unavailable)")
+            print("merge bench not run: this process has one device, so "
+                  "there is no tree reduce to compare")
     return out
 
 

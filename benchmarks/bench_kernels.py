@@ -62,11 +62,12 @@ def _fused_fold_section(rng, rows):
     m = rng.random(R) > 0.2
     g = rng.integers(0, G, R).astype(np.int32)
     got = fused_fold(jnp.asarray(x), jnp.asarray(m), jnp.asarray(g),
-                     num_groups=G)
+                     num_groups=G, interpret=True)
     want = fused_fold_numpy(x, m, g, num_groups=G)
     err = max(float(np.abs(np.asarray(got[n], np.float64)
                            - want[n]).max()) for n in names)
-    us = _time(lambda a, b, c: fused_fold(a, b, c, num_groups=G),
+    us = _time(lambda a, b, c: fused_fold(a, b, c, num_groups=G,
+                                          interpret=True),
                jnp.asarray(x), jnp.asarray(m), jnp.asarray(g))
 
     # measured XLA fold bytes (cost_analysis of the reference chunk scan)
@@ -107,7 +108,7 @@ def run(verbose: bool = True):
     R, F = 50, 262_144
     x = jnp.asarray(rng.normal(size=(R, F)).astype(np.float32))
     m = jnp.ones((R,), bool)
-    s, _, c = streaming_stats(x, m)
+    s, _, c = streaming_stats(x, m, interpret=True)
     rs, _, rc = streaming_stats_ref(x, m)
     err = float(jnp.abs(s - rs).max())
     us = _time(lambda a, b: streaming_stats(a, b, impl="ref"), x, m)
@@ -127,7 +128,7 @@ def run(verbose: bool = True):
     t.upload(sorted(gk), {
         "img": {"data": rng.normal(size=(64, 16, 16)).astype(np.float32)},
         "idx": {"size": rng.integers(6_000_000, 20_000_001, 64)}})
-    sess = GridSession(t, default_eta=8)
+    sess = GridSession(t, default_eta=8, fold_interpret=True)
     ref_res, _ = sess.run(MeanProgram(), impl="ref")
     pal_res, _ = sess.run(MeanProgram(), impl="pallas")
     err = float(jnp.abs(jnp.asarray(pal_res) - jnp.asarray(ref_res)).max())

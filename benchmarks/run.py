@@ -19,8 +19,12 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 from typing import Callable, Optional
+
+#: the checkout root: the persistent compilation cache lives under it
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _write_artifact(name: str, payload: dict) -> None:
@@ -214,6 +218,9 @@ def main() -> None:
                         help="CI-fast subset: every artifact bench except "
                              "the table-scheme sweep and the roofline")
     args = parser.parse_args()
+
+    from repro.utils import use_compile_cache
+    use_compile_cache(REPO)
 
     print("=" * 72)
     print("ColoGrid benchmarks (paper: HadoopBase-MIP backend, Bao et al. 2017)")

@@ -386,6 +386,10 @@ class DeviceBlock:
     spill_path: Optional[str] = None
     spill_nbytes: int = 0
     host_mmap: bool = False
+    # per-row shape of the host payload; the committed device copy may be
+    # flattened to [rows, features], so a host copy recovered from the
+    # device is reshaped back to it
+    row_shape: Tuple[int, ...] = ()
 
 
 @dataclasses.dataclass
@@ -974,7 +978,9 @@ class BlockStore:
             self._blocks.replace(key, blk)
             recovering = True
         if blk.device is not None:
-            host = np.ascontiguousarray(np.asarray(blk.device)[:blk.rows])
+            host = np.ascontiguousarray(
+                np.asarray(blk.device)[:blk.rows]).reshape(
+                    (blk.rows,) + blk.row_shape)
             host.flags.writeable = False
             new = dataclasses.replace(blk, host=host, host_mmap=False)
             if self._blocks.replace(key, new):
@@ -1009,6 +1015,7 @@ class BlockStore:
             rid=region.rid, family=family, qualifier=qualifier,
             version=key[3], rows=int(host.shape[0]),
             nbytes=int(host.nbytes), host=host,
+            row_shape=tuple(host.shape[1:]),
         )
         self.stats.inc(gathers=1)
         self._put_and_charge(key, blk)

@@ -42,10 +42,10 @@ from typing import Callable, Dict, Optional, Tuple
 MB = 1e6
 GB = 1e9
 
-#: Per-core VMEM of the TPU translation (~16 MB of on-chip vector memory
-#: feeding the compute units).  The fused fold kernel sizes its grouped
-#: accumulator pool against a fraction of this — the G threshold above
-#: which the engine falls back to the XLA fold (see
+#: Per-core scoped VMEM a Pallas kernel may use by default on TPU v5e
+#: (16 MiB; 16 MB leaves a margin).  The fused fold kernel sizes its
+#: grouped accumulator pool against this — the G threshold above which
+#: the engine falls back to the XLA fold (see
 #: ``repro.kernels.fused_fold.ops.max_groups_for_vmem``).
 VMEM_BYTES = 16 * MB
 

@@ -817,7 +817,8 @@ class GridSession:
 
         ``impl="pallas"`` swaps a sum/count-family program for its Pallas
         ``streaming_stats``-backed map phase (see
-        :func:`repro.kernels.streaming_stats.ops.kernel_map_program`);
+        :func:`repro.kernels.streaming_stats.ops.kernel_map_program`),
+        interpreted only under the session's ``fold_interpret``;
         ``impl="ref"``/``None`` keeps the jnp reference fold.  The kernel
         program has its own cache identity, so ref and pallas runs keep
         separate partials and can be compared side by side.
@@ -830,7 +831,8 @@ class GridSession:
         """
         if impl is not None and impl != "ref":
             from repro.kernels.streaming_stats.ops import kernel_map_program
-            program = kernel_map_program(program, impl=impl)
+            program = kernel_map_program(
+                program, impl=impl, interpret=self.engine.fold_interpret)
         q = self.scan().select(
             (family or self.payload_family,
              qualifier or self.payload_qualifier)).map(program)
@@ -1580,9 +1582,17 @@ class GridSession:
         ``rows`` stay logical; ``_run_blockwise`` extends row masks/gids to
         the padded shape host-side (tiny bool/int32 arrays).
 
+        Rows of more than one feature axis commit flattened, as a
+        ``[rows, features]`` slab: that is the layout the fused fold kernel
+        streams, so the fold reads the committed block in place.  A
+        multi-axis device array would need a whole-block relayout copy per
+        fold, and its own tiled layout pads each row on the chip.
+
         Transient injected transfer faults retry here under the session
         policy; :class:`DeviceLostError` propagates to
         :meth:`_fetch_block`, which owns quarantine + host degrade."""
+        if host.ndim > 2:
+            host = host.reshape(host.shape[0], int(np.prod(host.shape[1:])))
         bucket = self.engine.bucket_rows(len(host))
         if bucket != len(host):
             host = np.concatenate(

@@ -458,12 +458,20 @@ class MapReduceEngine:
         names = program.shared_fold_spec()
         grouped = groups > 0
         G = max(1, groups)
-        interpret = self.fold_interpret or jax.default_backend() != "tpu"
+        # the kernel compiles wherever the chip can run it; interpret mode
+        # is only ever the explicit CPU harness (fold_path admits non-TPU
+        # backends only under fold_interpret)
+        interpret = self.fold_interpret and jax.default_backend() != "tpu"
+        shape = tuple(row_shape)
 
         def fold(block, mask, gids):
             shared = fused_fold(
                 block, mask, gids, num_groups=G, names=names,
                 interpret=interpret)
+            # a device block is committed as a [rows, features] slab (see
+            # GridSession._put_block): restore the row shape on the pool
+            shared = {n: a if n == "count" else a.reshape(a.shape[:1] + shape)
+                      for n, a in shared.items()}
             if not grouped:   # ungrouped folds are the G=1 degenerate case
                 shared = {n: a[0] for n, a in shared.items()}
             return program.partial_from_shared(shared)
@@ -500,7 +508,7 @@ class MapReduceEngine:
                  else mask.astype(bool))
             v = block
             if pad:
-                v = jnp.pad(v, [(0, pad)] + [(0, 0)] * len(shape))
+                v = jnp.pad(v, [(0, pad)] + [(0, 0)] * (v.ndim - 1))
                 m = jnp.pad(m, [(0, pad)])
                 if groups:
                     gids = jnp.pad(gids, [(0, pad)])
@@ -815,8 +823,6 @@ class MapReduceEngine:
         if groups:
             args.append(jax.ShapeDtypeStruct((rows,), jnp.dtype(jnp.int32)))
         cost = fn.lower(*args).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):   # JAX 0.4.x wraps it in a list
-            cost = cost[0] if cost else {}
         return {"flops": float(cost.get("flops", 0.0)),
                 "bytes": float(cost.get("bytes accessed", 0.0))}
 

@@ -298,14 +298,13 @@ class TensorTable:
 
         ins = np.nonzero(~exists)[0]
         if len(ins):
-            ins_keys = new_keys[ins]
-            order = np.argsort(ins_keys, kind="stable")
-            ins_keys = ins_keys[order]
+            order = ins[np.argsort(new_keys[ins], kind="stable")]
+            ins_keys = new_keys[order]
             ins_pos = np.searchsorted(self._keys, ins_keys, side="left")
             self._keys = np.insert(self._keys, ins_pos, ins_keys)
             for kq, arr in arrays.items():
                 self._data[kq] = np.insert(
-                    self._data[kq], ins_pos, arr[ins][order], axis=0
+                    self._data[kq], ins_pos, arr[order], axis=0
                 )
             written += len(ins)
 

@@ -76,11 +76,19 @@ def synthetic_image_population(
     payload_shape: Tuple[int, ...] = (16, 16, 16),
     scale: float = 1.0,
     seed: int = 0,
+    region_bytes: int = 1 << 31,
 ) -> TensorTable:
     """The paper's study population per Table 3 strata (4,490 subjects;
     the paper's 5,153 figure counts *images* — some subjects have repeat
     scans), with logical sizes drawn from [SizeSmall, SizeBig] = [6, 20] MB.
-    ``scale`` < 1 shrinks each stratum proportionally for CI-speed runs."""
+    ``scale`` < 1 shrinks each stratum proportionally for CI-speed runs.
+
+    ``region_bytes`` is the hierarchical split threshold over those logical
+    sizes, so it sets how many subjects a region (and so a device block)
+    holds.  The sizes are drawn before the payload, so the region layout
+    depends on ``(scale, seed, region_bytes)`` and not on the volume shape.
+    Volumes are drawn as float32 normals straight into the table's payload
+    array: a full-size cohort never has a float64 copy on the host."""
     rng = np.random.default_rng(seed)
     rows = []
     for lo, hi, f_cnt, m_cnt in PAPER_STRATA:
@@ -93,6 +101,7 @@ def synthetic_image_population(
     sexes = np.array([r[1] for r in rows], np.int8)
     order = rng.permutation(n)
     ages, sexes = ages[order], sexes[order]
+    sizes = rng.integers(6_000_000, 20_000_001, n)
 
     table = TensorTable(
         "t1_population",
@@ -104,12 +113,12 @@ def synthetic_image_population(
                 ColumnSpec("sex", (), np.int8),
             )),
         ],
-        split_policy=HierarchicalSplitPolicy(max_region_bytes=1 << 31),
+        split_policy=HierarchicalSplitPolicy(max_region_bytes=region_bytes),
     )
-    data = rng.normal(0.0, 1.0, (n,) + payload_shape).astype(np.float32)
+    data = np.empty((n,) + tuple(payload_shape), np.float32)
+    rng.standard_normal(dtype=np.float32, out=data)
     # age covariate leaks into the volumes so subset averages differ measurably
-    data += ages[:, None, None, None] / 100.0
-    sizes = rng.integers(6_000_000, 20_000_001, n)
+    data += (ages / np.float32(100.0)).reshape((n,) + (1,) * len(payload_shape))
     table.upload(
         [f"sub{i:06d}" for i in range(n)],
         {"img": {"data": data},

@@ -17,6 +17,7 @@ switch) and ``ref.py`` (pure oracle used by the allclose sweeps):
 - ``ssm_scan``         — chunked SSD recurrence (mamba2 / zamba2 / long
   context decode).
 
-CPU container note: kernels are TARGETED at TPU (tile sizes chosen for
-VMEM and the 128×128 MXU) and VALIDATED here with ``interpret=True``.
+Kernels target the TPU (tile sizes chosen for VMEM and the 128×128 MXU)
+and compile by default.  CPU runs and tests validate them with an explicit
+``interpret=True`` (``fold_interpret=True`` at the session level).
 """

@@ -25,8 +25,13 @@ HBM→VMEM exactly once and the full grouped shared-accumulator pool
 Ungrouped folds are the ``G = 1`` degenerate case: every valid row lands in
 group 0 and the one-hot weights collapse to the row mask.
 
-CPU container note: targeted at TPU (G padded to sublane multiples, BF in
-128-lane units), validated with ``interpret=True``.
+Targeted at TPU (G padded to sublane multiples, BF in 128-lane units).
+The grid covers ragged edges instead of padding the block: the last
+feature tile's out-of-range columns only ever reach out-of-range output
+columns (which are never written back), and the last row block's
+out-of-range rows are masked off in-kernel by their row index.  So a
+block folds as it stands, with no whole-block pad copy in HBM.  CPU runs
+validate the same kernel with ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -47,7 +52,7 @@ ACC_ORDER: Tuple[str, ...] = ("count", "s1", "s2", "s3", "s4")
 
 
 def _fused_fold_kernel(x_ref, g_ref, m_ref, *out_refs,
-                       names: Tuple[str, ...], n_groups: int):
+                       names: Tuple[str, ...], n_groups: int, n_rows: int):
     """One (feature-tile, row-block) grid cell.
 
     x_ref    [BR, BF]   payload tile (any real dtype; cast to fp32)
@@ -56,6 +61,8 @@ def _fused_fold_kernel(x_ref, g_ref, m_ref, *out_refs,
     out_refs             fp32 accumulators in ``names`` order:
                          count [G, 1]; s1..s4 [G, BF] — revisited across the
                          row sweep, initialized at row-block 0
+    n_rows               the block's real row count: rows of a ragged last
+                         row block at or past it are masked off
     """
     j = pl.program_id(1)  # row-block index (innermost, sequential)
 
@@ -68,6 +75,10 @@ def _fused_fold_kernel(x_ref, g_ref, m_ref, *out_refs,
     m = m_ref[...].astype(jnp.float32)             # [BR, 1]
     g = g_ref[...]                                 # [BR, 1] int32
     br = x.shape[0]
+    if n_rows % br:
+        # ragged last row block: its tail reads past the block's end
+        row = j * br + jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
+        m = jnp.where(row < n_rows, m, 0.0)
 
     # one-hot group weights: w[r, g] = 1 iff row r is valid AND gid(r) == g
     gid_iota = jax.lax.broadcasted_iota(jnp.int32, (br, n_groups), 1)
@@ -80,6 +91,7 @@ def _fused_fold_kernel(x_ref, g_ref, m_ref, *out_refs,
     def seg(v):                                    # [BR, X] -> [G, X]
         return jax.lax.dot_general(
             w, v, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     refs = iter(out_refs)
@@ -102,7 +114,7 @@ def _fused_fold_kernel(x_ref, g_ref, m_ref, *out_refs,
     static_argnames=("names", "n_groups", "block_rows", "block_features",
                      "interpret"))
 def fused_fold_pallas(
-    x: jax.Array,            # [R, F] — R, F already block multiples
+    x: jax.Array,            # [R, F] — any R, F (ragged edges are masked)
     gids: jax.Array,         # [R] int32
     mask: jax.Array,         # [R] float 0/1
     names: Tuple[str, ...],
@@ -120,8 +132,7 @@ def fused_fold_pallas(
     R, F = x.shape
     br = min(block_rows, R)
     bf = min(block_features, F)
-    assert R % br == 0 and F % bf == 0, (R, F, br, bf)
-    grid = (F // bf, R // br)
+    grid = (pl.cdiv(F, bf), pl.cdiv(R, br))
 
     g2 = gids.reshape(R, 1).astype(jnp.int32)
     m2 = mask.reshape(R, 1).astype(jnp.float32)
@@ -141,7 +152,7 @@ def fused_fold_pallas(
 
     return pl.pallas_call(
         functools.partial(_fused_fold_kernel, names=names,
-                          n_groups=n_groups),
+                          n_groups=n_groups, n_rows=R),
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, bf), lambda i, j: (j, i)),

@@ -1,10 +1,11 @@
 """Public op: fused grouped power-sum fold over a block of rows.
 
-Handles arbitrary row shapes (flattens features), pads rows/features/groups
-to tile multiples (padded rows carry zero mask, padded groups receive no
-rows), dispatches to the Pallas kernel, and exposes the analytic cost and
-VMEM-budget helpers the engine's ``fold_path`` dispatch and the roofline
-probe consult.
+Handles arbitrary row shapes (flattens features), pads groups to a sublane
+multiple (padded groups receive no rows), dispatches to the Pallas kernel,
+and exposes the analytic cost and VMEM-budget helpers the engine's
+``fold_path`` dispatch and the roofline probe consult.  Rows and features
+are never padded: the kernel's grid covers ragged edges itself, so a
+block is read in place rather than copied to a tile multiple first.
 
 The op's contract is the CSE shared-accumulator pool of
 ``repro.core.stats``: ``{name: array}`` with ``count`` of shape ``[G]`` and
@@ -28,11 +29,6 @@ from repro.kernels.fused_fold.kernel import (
     DEFAULT_BLOCK_ROWS,
     fused_fold_pallas,
 )
-
-#: fraction of per-core VMEM the grouped accumulator pool may claim (the
-#: other half stays for double-buffered input tiles and the one-hot
-#: weights), mirroring the chunk model's "stats may only claim half" rule
-VMEM_FRACTION = 0.5
 
 
 def canonical_names(names: Tuple[str, ...]) -> Tuple[str, ...]:
@@ -63,7 +59,7 @@ def fused_fold(
     names: Tuple[str, ...] = ACC_ORDER,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     block_features: int = DEFAULT_BLOCK_FEATURES,
-    interpret: bool = True,          # CPU container: interpret by default
+    interpret: bool = False,         # True only for CPU runs and tests
 ) -> Dict[str, jax.Array]:
     """-> ``{name: acc}``: count ``[G]``, s_k ``[G, *feature_shape]`` fp32.
 
@@ -83,24 +79,16 @@ def fused_fold(
     g = (jnp.zeros((R,), jnp.int32) if gids is None
          else gids.astype(jnp.int32))
 
-    br = min(block_rows, max(8, R))
-    bf = min(block_features, max(128, F))
-    pr = -R % br
-    pf = -F % bf
-    if pr or pf:
-        x = jnp.pad(x, ((0, pr), (0, pf)))
-        m = jnp.pad(m, ((0, pr),))     # pad rows are masked off
-        g = jnp.pad(g, ((0, pr),))
     Gp = _pad_groups(G)
 
-    outs = fused_fold_pallas(x, g, m, names, Gp, br, bf,
+    outs = fused_fold_pallas(x, g, m, names, Gp, block_rows, block_features,
                              interpret=interpret)
     result: Dict[str, jax.Array] = {}
     for n, o in zip(names, outs):
         if n == "count":
             result[n] = o[:G, 0]
         else:
-            result[n] = o[:G, :F].reshape((G,) + fshape)
+            result[n] = o[:G].reshape((G,) + fshape)
     return result
 
 
@@ -139,18 +127,28 @@ def max_groups_for_vmem(
     names: Tuple[str, ...] = ACC_ORDER,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     block_features: int = DEFAULT_BLOCK_FEATURES,
-    vmem_bytes: float = VMEM_BYTES * VMEM_FRACTION,
+    vmem_bytes: float = VMEM_BYTES,
 ) -> int:
-    """Largest G whose fp32 accumulator pool (plus the input tile and the
-    one-hot weights) fits the kernel's VMEM budget — the engine falls back
-    to the XLA fold above this.  Derived from the chunk model's per-core
-    VMEM constant, halved like its HBM "stats may only claim half" rule."""
+    """Largest G (a sublane multiple, so the kernel gets it unpadded) whose
+    kernel fits the per-core scoped VMEM — the engine falls back to the
+    XLA fold above this.  Counts what the kernel holds in VMEM at once:
+
+    - fixed: the input tile, double-buffered, plus its fp32 cast and
+      square (fp32 worst case), and the double-buffered gid/mask tiles,
+      lane-padded to 128;
+    - per group: every wide accumulator block, double-buffered; one
+      ``[G, BF]`` contraction result; the one-hot weight column; the
+      lane-padded count row.
+
+    Checked against the TPU compiler for v5e, which refuses a G a few
+    percent above this limit (``tests/test_tpu_compile.py`` compiles at
+    it)."""
     names = canonical_names(names)
     n_wide = sum(1 for n in names if n != "count")
-    fixed = block_rows * block_features * 4        # input tile, fp32 worst
-    per_group = (n_wide * block_features + 1) * 4  # accumulator rows
-    per_group += block_rows * 4                    # one-hot weight column
+    br, bf = block_rows, block_features
+    fixed = 4 * br * bf * 4 + 2 * 2 * br * 128 * 4
+    per_group = 4 * (2 * n_wide * bf + bf + br + 256)
     budget = vmem_bytes - fixed
     if budget <= 0:
         return 0
-    return max(0, int(budget // per_group))
+    return int(budget // per_group) // 8 * 8

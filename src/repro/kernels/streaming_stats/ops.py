@@ -30,7 +30,7 @@ def streaming_stats(
     rows: jax.Array,       # [R, *feature_shape]
     mask: jax.Array,       # [R]
     impl: str = "pallas",
-    interpret: bool = True,   # CPU container: interpret by default
+    interpret: bool = False,  # True only for CPU runs and tests
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """-> (sum, sumsq, count); sum/sumsq have the row's feature shape."""
     if impl == "ref":
@@ -47,7 +47,7 @@ def streaming_stats(
 class KernelMeanProgram(MapReduceProgram):
     """MeanProgram with the Pallas kernel as the map-phase fold."""
 
-    interpret: bool = True
+    interpret: bool = False
     additive = True
 
     def zero(self, row_shape, dtype):
@@ -72,7 +72,7 @@ class KernelSecondMomentProgram(MapReduceProgram):
     (raw-sums form instead of the Chan merge; equal up to float
     associativity, and additive so the reduce stays one ``psum``)."""
 
-    interpret: bool = True
+    interpret: bool = False
     additive = True
 
     def zero(self, row_shape, dtype):
@@ -94,7 +94,7 @@ class KernelSecondMomentProgram(MapReduceProgram):
 
 
 def kernel_map_program(program: MapReduceProgram, impl: str = "pallas",
-                       interpret: bool = True) -> MapReduceProgram:
+                       interpret: bool = False) -> MapReduceProgram:
     """The Pallas map-phase twin of a sum/count-family program.
 
     ``GridSession.run(..., impl="pallas")`` routes through here: the

@@ -64,8 +64,6 @@ def compile_cell(cfg, shape: str, mesh, kind: str) -> Dict:
         if hasattr(mem, k)
     }
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # JAX 0.4.x wraps it in a list
-        cost = cost[0] if cost else {}
     coll = collective_bytes_from_hlo(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

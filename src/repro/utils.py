@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import os
 from typing import Sequence, Tuple
 
 import numpy as np
@@ -17,43 +18,37 @@ def shard_map_compat(
     axis_names=None,
     check: bool = False,
 ):
-    """``shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    JAX 0.4.x ships it as ``jax.experimental.shard_map.shard_map(...,
-    auto=..., check_rep=...)`` where ``auto`` is the *complement* of the
-    manual axes.  ``axis_names=None`` means manual over every mesh axis.
-    """
-    try:
-        from jax import shard_map as _shard_map  # JAX >= 0.6
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _shard_map(f, **kwargs)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        # NOTE: partial-manual (`auto=`) on 0.4.x trips a fatal XLA sharding
-        # check on CPU, so the compat path runs fully manual: axes absent
-        # from the specs are replicated, which preserves results (collectives
-        # only name the manual axes) at some redundant compute.
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` manual over ``axis_names`` (every mesh axis when
+    ``None``), with ``check`` as its ``check_vma``."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` pinned to Auto axis types (portable across JAX 0.8/0.9).
+    """``jax.make_mesh`` with every axis of Auto type."""
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
-    JAX 0.4.x has neither ``AxisType`` nor the ``axis_types`` kwarg — there
-    every mesh axis is Auto already, so the plain call is equivalent.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape),
-            tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+
+def use_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point; returns
+    its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX
+    (which reads it itself); otherwise the cache sits at ``<root>/.jax_cache``
+    — one fixed path, since the path is part of what a cache hit matches.
+    Entry points call this; importing the library never does, so the test
+    suite compiles uncached."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_size_bytes(tree) -> int:

@@ -369,7 +369,7 @@ class TestFusedCSE:
 class TestPallasMapPhase:
     def test_mean_ref_vs_pallas_equivalence(self):
         t = make_table(per=10, seed=2)
-        s = GridSession(t, default_eta=4)
+        s = GridSession(t, default_eta=4, fold_interpret=True)
         ref, _ = s.run(MeanProgram(), impl="ref")
         pal, rep = s.run(MeanProgram(), impl="pallas")
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
@@ -378,7 +378,7 @@ class TestPallasMapPhase:
 
     def test_variance_ref_vs_pallas_equivalence(self):
         t = make_table(per=10, seed=2)
-        s = GridSession(t, default_eta=4)
+        s = GridSession(t, default_eta=4, fold_interpret=True)
         ref, _ = s.run(VarianceProgram())
         pal, _ = s.run(VarianceProgram(), impl="pallas")
         np.testing.assert_allclose(np.asarray(pal["mean"]),
@@ -389,7 +389,7 @@ class TestPallasMapPhase:
 
     def test_pallas_partials_cache_separately_from_ref(self):
         t = make_table(per=10, seed=2)
-        s = GridSession(t, default_eta=4)
+        s = GridSession(t, default_eta=4, fold_interpret=True)
         s.run(MeanProgram())
         _, rep = s.run(MeanProgram(), impl="pallas")
         assert rep.query.partials_reused == 0      # kernel identity differs

@@ -11,8 +11,8 @@ signatures fall back to XLA; pallas fold executables stay keyed on the
 pow2 row bucket and are chunk-free (η never enters the key); and the gid
 block cache makes dirty-region re-folds skip re-densifying group ids.
 
-Runs entirely in Pallas interpret mode on CPU (``fold_interpret=True`` /
-the op's ``interpret=True`` default).
+Runs entirely in Pallas interpret mode on CPU, asked for explicitly
+(``fold_interpret=True`` / the op's ``interpret=True``).
 """
 
 import numpy as np
@@ -76,7 +76,7 @@ class TestKernelVsOracle:
         m = rng.random(R) > 0.25
         g = rng.integers(0, G, R).astype(np.int32)
         got = fused_fold(jnp.asarray(x), jnp.asarray(m), jnp.asarray(g),
-                         num_groups=G)
+                         num_groups=G, interpret=True)
         want = fused_fold_numpy(x, m, g, num_groups=G)
         assert got["count"].shape == (G,)
         assert got["s1"].shape == (G,) + shape
@@ -88,7 +88,7 @@ class TestKernelVsOracle:
         x = jnp.asarray(x32).astype(jnp.bfloat16)
         m = rng.random(50) > 0.3
         g = rng.integers(0, G, 50).astype(np.int32)
-        got = fused_fold(x, jnp.asarray(m), jnp.asarray(g), num_groups=G)
+        got = fused_fold(x, jnp.asarray(m), jnp.asarray(g), num_groups=G, interpret=True)
         want = fused_fold_numpy(np.asarray(x, np.float32), m, g,
                                 num_groups=G)
         # bf16 rows: ~3 significand digits; s4 amplifies to ~1e-1
@@ -102,21 +102,21 @@ class TestKernelVsOracle:
         m = rng.random(40) > 0.5
         g = rng.integers(0, G, 40).astype(np.int32)
         got = fused_fold(jnp.asarray(x), jnp.asarray(m), jnp.asarray(g),
-                         num_groups=G)
+                         num_groups=G, interpret=True)
         # small ints: fp32 accumulation is exact
         assert_pool_close(got, fused_fold_numpy(x, m, g, num_groups=G),
                           rtol=0, atol=0)
 
     def test_defaults_are_ungrouped_unmasked(self):
         x = rng.normal(size=(20, 8)).astype(np.float32)
-        got = fused_fold(jnp.asarray(x))
+        got = fused_fold(jnp.asarray(x), interpret=True)
         assert_pool_close(got, fused_fold_numpy(x))
 
     def test_accumulator_subset(self):
         x = rng.normal(size=(33, 9)).astype(np.float32)
         m = rng.random(33) > 0.4
         got = fused_fold(jnp.asarray(x), jnp.asarray(m),
-                         names=("count", "s1", "s2"))
+                         names=("count", "s1", "s2"), interpret=True)
         assert set(got) == {"count", "s1", "s2"}
         assert_pool_close(
             got, fused_fold_numpy(x, m, names=("count", "s1", "s2")))
@@ -124,20 +124,21 @@ class TestKernelVsOracle:
     def test_empty_groups_stay_zero(self):
         x = rng.normal(size=(16, 4)).astype(np.float32)
         g = np.zeros(16, np.int32)          # everything lands in group 0
-        got = fused_fold(jnp.asarray(x), None, jnp.asarray(g), num_groups=5)
+        got = fused_fold(jnp.asarray(x), None, jnp.asarray(g), num_groups=5, interpret=True)
         np.testing.assert_array_equal(np.asarray(got["count"])[1:], 0)
         np.testing.assert_array_equal(np.asarray(got["s2"])[1:], 0)
 
     def _check_ragged(self, R, F, G, seed):
-        """Ragged R/F exercise the pad-to-tile path: padded rows carry
-        zero mask, padded groups receive no rows — the oracle never sees
-        any of it."""
+        """Ragged R/F exercise the kernel's ragged edge tiles: rows past
+        the block's end are masked off by row index, columns past it only
+        reach output columns that are never written, padded groups
+        receive no rows — the oracle never sees any of it."""
         r = np.random.default_rng(seed)
         x = r.normal(size=(R, F)).astype(np.float32)
         m = r.random(R) > 0.5
         g = r.integers(0, G, R).astype(np.int32)
         got = fused_fold(jnp.asarray(x), jnp.asarray(m), jnp.asarray(g),
-                         num_groups=G)
+                         num_groups=G, interpret=True)
         want = fused_fold_numpy(x, m, g, num_groups=G)
         assert_pool_close(got, want, rtol=1e-3, atol=1e-2)
         np.testing.assert_array_equal(np.asarray(got["count"]),
@@ -173,14 +174,14 @@ class TestKernelVsOracle:
         x[17, ::2] = -np.inf
         g = rng.integers(0, 3, 24).astype(np.int32)
         got = fused_fold(jnp.asarray(x), jnp.asarray(m), jnp.asarray(g),
-                         num_groups=3)
+                         num_groups=3, interpret=True)
         for n, a in got.items():
             assert bool(jnp.isfinite(a).all()), n
         assert_pool_close(got, fused_fold_numpy(x, m, g, num_groups=3))
 
     def test_all_masked(self):
         x = rng.normal(size=(32, 16)).astype(np.float32)
-        got = fused_fold(jnp.asarray(x), jnp.asarray(np.zeros(32, bool)))
+        got = fused_fold(jnp.asarray(x), jnp.asarray(np.zeros(32, bool)), interpret=True)
         for a in got.values():
             np.testing.assert_array_equal(np.asarray(a), 0)
 

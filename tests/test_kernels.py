@@ -32,7 +32,8 @@ class TestStreamingStats:
     def test_matches_ref(self, R, shape, dtype):
         x = rng.normal(size=(R,) + shape).astype(dtype)
         m = rng.random(R) > 0.25
-        s, sq, c = streaming_stats(jnp.asarray(x), jnp.asarray(m))
+        s, sq, c = streaming_stats(jnp.asarray(x), jnp.asarray(m),
+                                   interpret=True)
         rs, rsq, rc = streaming_stats_ref(
             jnp.asarray(x.reshape(R, -1)), jnp.asarray(m))
         tol = 1e-5 if dtype == np.float32 else 5e-3
@@ -52,7 +53,8 @@ class TestStreamingStats:
         r = np.random.default_rng(seed)
         x = r.normal(size=(R, F)).astype(np.float32)
         m = r.random(R) > 0.5
-        s, _, c = streaming_stats(jnp.asarray(x), jnp.asarray(m))
+        s, _, c = streaming_stats(jnp.asarray(x), jnp.asarray(m),
+                                  interpret=True)
         np.testing.assert_allclose(
             np.asarray(s), (x * m[:, None]).sum(0), rtol=1e-4, atol=1e-4)
         assert float(c) == m.sum()
@@ -60,7 +62,8 @@ class TestStreamingStats:
     def test_all_masked(self):
         x = rng.normal(size=(32, 16)).astype(np.float32)
         m = np.zeros(32, bool)
-        s, sq, c = streaming_stats(jnp.asarray(x), jnp.asarray(m))
+        s, sq, c = streaming_stats(jnp.asarray(x), jnp.asarray(m),
+                                   interpret=True)
         assert float(c) == 0
         np.testing.assert_array_equal(np.asarray(s), 0)
 
@@ -73,7 +76,8 @@ class TestStreamingStats:
         vals = x.reshape(D, 60 // D, 24)
         valid = np.ones((D, 60 // D), bool)
         res, _ = MapReduceEngine(mesh).run(
-            KernelMeanProgram(), jnp.asarray(vals), jnp.asarray(valid), 10)
+            KernelMeanProgram(interpret=True), jnp.asarray(vals),
+            jnp.asarray(valid), 10)
         np.testing.assert_allclose(np.asarray(res), x.mean(0), atol=1e-5)
 
 
