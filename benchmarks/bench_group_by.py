@@ -20,11 +20,21 @@ Two comparisons back the PR's perf claims:
 
 Artifact: ``BENCH_group_by.json`` via benchmarks/run.py (also in
 ``--smoke``; CI uploads it and the perf gate checks the headline
-``grouped_speedup_vs_loop``).
+``grouped_speedup_vs_loop``).  The smoke runs on one device, so the tree
+merge is gated on its own where there are several::
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python -m benchmarks.bench_group_by --merge-gate
+
+which fails when the process has one device or the speedup regresses
+past ``merge_tree_speedup`` in ``benchmarks/perf_baselines.json``.
 """
 
 from __future__ import annotations
 
+import argparse
+import json
+import sys
 import time
 
 import numpy as np
@@ -32,6 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from benchmarks.check_regression import DEFAULT_BASELINES, check_metric
 from repro.core.grid import GridSession
 from repro.core.mapreduce import MapReduceEngine
 from repro.core.regions import HierarchicalSplitPolicy
@@ -245,5 +256,32 @@ def run(verbose: bool = True):
     return out
 
 
+def merge_gate(baselines: str = DEFAULT_BASELINES) -> int:
+    """Time the tree merge against the funnel and gate the speedup: exit
+    code 0 within tolerance, 1 on a regression or with one device."""
+    merge = _merge_bench()
+    if not merge:
+        print("merge gate FAILED: this process has one device, so there "
+              "is no tree reduce to time")
+        return 1
+    with open(baselines) as f:
+        spec = json.load(f)
+    m = spec["metrics"]["group_by"]["merge_tree_speedup"]
+    ok, line = check_metric(
+        "group_by.merge_tree_speedup", merge["funnel_s"] / merge["tree_s"],
+        float(m["baseline"]), m.get("direction", "higher"),
+        float(m.get("tolerance", spec.get("default_tolerance", 0.25))))
+    print(f"merge @256 partials x {merge['devices']} dev: "
+          f"tree={merge['tree_s']*1e3:.2f}ms "
+          f"funnel={merge['funnel_s']*1e3:.2f}ms")
+    print(line)
+    return 0 if ok else 1
+
+
 if __name__ == "__main__":
+    parser = argparse.ArgumentParser(description=__doc__)
+    parser.add_argument("--merge-gate", action="store_true",
+                        help="time and gate only the tree merge")
+    if parser.parse_args().merge_gate:
+        sys.exit(merge_gate())
     run()

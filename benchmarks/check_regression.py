@@ -87,8 +87,8 @@ def run_gate(bench_dir: str, baselines_path: str) -> Tuple[bool, List[str]]:
                 continue
             value = float(payload[metric])
             if m.get("optional") and value == 0.0:
-                # optional probes report 0 when their environment (e.g. a
-                # multi-device subprocess) is unavailable — not a regression
+                # optional probes report 0 when their environment (e.g.
+                # several devices) is unavailable — not a regression
                 lines.append(f"{label}: 0.0 (optional probe unavailable), "
                              f"skipped")
                 continue

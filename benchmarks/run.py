@@ -198,7 +198,7 @@ def run_kernels() -> None:
 
     _run_bench(
         "kernels",
-        "Kernels (interpret-mode validation)",
+        "Kernels (oracle agreement + op accounting)",
         bench_kernels.run,
         lambda b: (f"fused_fold_bytes_x="
                    f"{b['fused_fold_speedup_grouped']:.2f};"

@@ -818,7 +818,8 @@ class GridSession:
         ``impl="pallas"`` swaps a sum/count-family program for its Pallas
         ``streaming_stats``-backed map phase (see
         :func:`repro.kernels.streaming_stats.ops.kernel_map_program`),
-        interpreted only under the session's ``fold_interpret``;
+        interpreted only off the chip under the session's
+        ``fold_interpret``;
         ``impl="ref"``/``None`` keeps the jnp reference fold.  The kernel
         program has its own cache identity, so ref and pallas runs keep
         separate partials and can be compared side by side.
@@ -832,7 +833,7 @@ class GridSession:
         if impl is not None and impl != "ref":
             from repro.kernels.streaming_stats.ops import kernel_map_program
             program = kernel_map_program(
-                program, impl=impl, interpret=self.engine.fold_interpret)
+                program, impl=impl, interpret=self.engine.kernel_interpret)
         q = self.scan().select(
             (family or self.payload_family,
              qualifier or self.payload_qualifier)).map(program)

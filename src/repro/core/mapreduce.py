@@ -276,6 +276,10 @@ class MapReduceEngine:
         #: platforms take the XLA fold — interpret mode is a correctness
         #: harness, not a fast path.
         self.fold_interpret = bool(fold_interpret)
+        #: what the kernels are given: interpret mode only off the chip,
+        #: so a TPU always compiles them whatever ``fold_interpret`` says
+        self.kernel_interpret = (self.fold_interpret
+                                 and jax.default_backend() != "tpu")
         #: folds dispatched per implementation (observability + tests);
         #: bumped under ``_count_lock`` — concurrent frontend queries fold
         #: from many threads and the barrier tests assert EXACT counts
@@ -458,10 +462,8 @@ class MapReduceEngine:
         names = program.shared_fold_spec()
         grouped = groups > 0
         G = max(1, groups)
-        # the kernel compiles wherever the chip can run it; interpret mode
-        # is only ever the explicit CPU harness (fold_path admits non-TPU
-        # backends only under fold_interpret)
-        interpret = self.fold_interpret and jax.default_backend() != "tpu"
+        # fold_path admits non-TPU backends only under fold_interpret
+        interpret = self.kernel_interpret
         shape = tuple(row_shape)
 
         def fold(block, mask, gids):
@@ -512,7 +514,11 @@ class MapReduceEngine:
                 m = jnp.pad(m, [(0, pad)])
                 if groups:
                     gids = jnp.pad(gids, [(0, pad)])
-            v = v.reshape((n_chunks, eta) + shape)
+            # split rows only: a block committed as a [rows, features] slab
+            # (GridSession._put_block) takes its row shape back one chunk
+            # at a time, so on the chip's tiled layout the relayout copy is
+            # one chunk, never the whole block
+            v = v.reshape((n_chunks, eta) + v.shape[1:])
             m = m.reshape((n_chunks, eta))
             init = program.zero(shape, dtype)
 
@@ -521,6 +527,7 @@ class MapReduceEngine:
 
                 def gbody(carry, xs):
                     chunk, cm, cg = xs
+                    chunk = chunk.reshape((eta,) + shape)
                     gm = (cg[None, :] == jnp.arange(groups)[:, None]) \
                         & cm[None, :]
                     return program.merge(carry,
@@ -531,6 +538,7 @@ class MapReduceEngine:
 
             def body(carry, xs):
                 chunk, cm = xs
+                chunk = chunk.reshape((eta,) + shape)
                 return program.merge(carry, program.map_chunk(chunk, cm)), None
 
             partial, _ = jax.lax.scan(body, init, (v, m))

@@ -9,10 +9,15 @@ description quotes:
 """
 
 import json
+import os
+import subprocess
+import sys
 
 import pytest
 
 from benchmarks.check_regression import check_metric, run_gate
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def write(path, payload):
@@ -80,8 +85,8 @@ class TestPerfGate:
         assert not ok
 
     def test_optional_probe_zero_is_skipped(self, tmp_path, baselines):
-        # the multi-device merge probe reports 0 where the subprocess is
-        # unavailable — that is "no data", not a regression
+        # the merge probe reports 0 on one device, where there is no
+        # tree to time — that is "no data", not a regression
         emit(tmp_path, probe=0.0)
         ok, lines = run_gate(str(tmp_path), str(baselines))
         assert ok, lines
@@ -109,3 +114,29 @@ class TestPerfGate:
                 assert m.get("direction") in ("higher", "lower"), (bench,
                                                                    name)
                 assert float(m["baseline"]) > 0
+
+
+def _merge_gate(devices):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO, "src"),
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    return subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_group_by", "--merge-gate"],
+        capture_output=True, text=True, env=env, cwd=REPO, timeout=600)
+
+
+class TestMergeGate:
+    """The tree-merge gate CI runs on its multi-device job: it times the
+    tree against the funnel where there are devices to tree over, and
+    fails loudly where there is one."""
+
+    def test_times_and_gates_on_several_devices(self):
+        proc = _merge_gate(4)
+        assert "x 4 dev" in proc.stdout, proc.stdout + proc.stderr
+        assert "group_by.merge_tree_speedup:" in proc.stdout
+        assert proc.returncode in (0, 1)     # the verdict is timing's
+
+    def test_fails_with_one_device(self):
+        proc = _merge_gate(1)
+        assert proc.returncode == 1
+        assert "merge gate FAILED" in proc.stdout
