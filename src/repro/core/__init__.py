@@ -25,6 +25,8 @@ The subpackage mirrors HadoopBase-MIP's backend (Bao et al., 2017):
 - :mod:`repro.core.frontend`    — :class:`GridFrontend`, concurrent query
   serving: single-flight coalescing, batched device ticks, epoch-isolated
   mutation, admission control.
+- :mod:`repro.core.spans`       — per-query spans and timing records
+  (``RunReport.trace``) on the profiler's clock.
 """
 
 from repro.core.table import TensorTable, ColumnFamily, ColumnSpec

@@ -54,13 +54,16 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import queue
 import threading
 import time
-from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as _FutureTimeout
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
+from repro.core import spans
 from repro.core.blockstore import AtomicStats, LRUCache
 from repro.core.faults import (
     DeviceLostError,
@@ -84,11 +87,7 @@ class QueryTimeoutError(TimeoutError):
 @dataclasses.dataclass
 class FrontendStats(AtomicStats):
     """Observable serving counters (atomic; read via ``snapshot()``).
-
-    Latency percentiles come from a bounded reservoir of recent
-    per-query service times — :meth:`latency_percentiles` — not from the
-    dataclass fields, so ``snapshot()`` stays a cheap field copy.
-    """
+    Per-query timings live on each answer's ``RunReport.trace``."""
 
     submitted: int = 0          # submit() calls admitted
     served: int = 0             # futures resolved with a result
@@ -106,30 +105,6 @@ class FrontendStats(AtomicStats):
     retries: int = 0            # dispatch-level query re-executions
     faults: int = 0             # fault-kind failures observed at dispatch
     breaker_opens: int = 0      # per-plan circuit breakers tripped open
-
-    def __post_init__(self):
-        super().__post_init__()
-        object.__setattr__(self, "_lat", deque(maxlen=2048))
-        object.__setattr__(self, "_lat_lock", threading.Lock())
-
-    def record_latency(self, seconds: float) -> None:
-        with self._lat_lock:
-            self._lat.append(seconds)
-
-    def latency_percentiles(self) -> Tuple[float, float]:
-        """``(p50, p99)`` service latency in seconds over the reservoir."""
-        with self._lat_lock:
-            lat = sorted(self._lat)
-        if not lat:
-            return 0.0, 0.0
-        return (lat[len(lat) // 2],
-                lat[min(len(lat) - 1, (len(lat) * 99) // 100)])
-
-    def reset_latencies(self) -> None:
-        """Drop the reservoir (benches call this after warm-up so compile
-        latencies don't pollute the steady-state percentiles)."""
-        with self._lat_lock:
-            self._lat.clear()
 
 
 class _EpochRWLock:
@@ -206,7 +181,7 @@ class _Task:
     eta: Optional[int]
     deadline: Optional[float]      # monotonic absolute, None = no deadline
     future: Future
-    t_submit: float
+    trace: spans.QueryTrace
     flight_key: Optional[Tuple] = None
     breaker_key: Optional[Tuple] = None
     # resolution claim: exactly ONE of _finish / _fail / _abandon settles
@@ -301,6 +276,12 @@ class GridFrontend:
             target=self._scheduler_loop, name="grid-frontend-tick",
             daemon=True)
         self._scheduler.start()
+        # stamps each execution's device wait once its results are ready,
+        # off the workers' path: an answer never waits for it
+        self._ready: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._watcher = threading.Thread(
+            target=self._watch_loop, name="grid-frontend-ready", daemon=True)
+        self._watcher.start()
 
     # ------------------------------------------------------------------
     # client surface
@@ -321,54 +302,56 @@ class GridFrontend:
 
     def _submit(self, plan: GridQuery, *, eta: Optional[int],
                 deadline: Optional[float]) -> _Task:
-        if self._closed:
-            raise RuntimeError("frontend is closed")
-        bkey: Optional[Tuple] = None
-        if self.breaker_threshold > 0:
-            bkey = plan.signature()
-            with self._breaker_lock:
-                br = self._breakers.peek(bkey)
-                open_until = 0.0 if br is None else br.opened_until
-            if time.monotonic() < open_until:
-                self.stats.inc(rejected=1)
-                raise QueryFaultedError(
-                    "circuit breaker open for this plan "
-                    f"(cooldown {self.breaker_cooldown_s}s after "
-                    f"{self.breaker_threshold} consecutive faults)")
-        with self._open_lock:
-            if self._open >= self.max_pending:
-                self.stats.inc(rejected=1)
-                raise FrontendOverloadedError(
-                    f"{self._open} open queries >= max_pending="
-                    f"{self.max_pending}")
-            self._open += 1
+        trace = spans.QueryTrace()
+        with spans.active(trace), spans.span("frontend.submit"):
+            if self._closed:
+                raise RuntimeError("frontend is closed")
+            bkey: Optional[Tuple] = None
+            if self.breaker_threshold > 0:
+                bkey = plan.signature()
+                with self._breaker_lock:
+                    br = self._breakers.peek(bkey)
+                    open_until = 0.0 if br is None else br.opened_until
+                if time.monotonic() < open_until:
+                    self.stats.inc(rejected=1)
+                    raise QueryFaultedError(
+                        "circuit breaker open for this plan "
+                        f"(cooldown {self.breaker_cooldown_s}s after "
+                        f"{self.breaker_threshold} consecutive faults)")
+            with self._open_lock:
+                if self._open >= self.max_pending:
+                    self.stats.inc(rejected=1)
+                    raise FrontendOverloadedError(
+                        f"{self._open} open queries >= max_pending="
+                        f"{self.max_pending}")
+                self._open += 1
 
-        now = time.monotonic()
-        fut: Future = Future()
-        task = _Task(plan=plan, eta=eta,
-                     deadline=None if deadline is None else now + deadline,
-                     future=fut, t_submit=now, breaker_key=bkey)
-        self.stats.inc(submitted=1)
+            now = time.monotonic()
+            fut: Future = Future()
+            task = _Task(plan=plan, eta=eta,
+                         deadline=None if deadline is None else now + deadline,
+                         future=fut, trace=trace, breaker_key=bkey)
+            self.stats.inc(submitted=1)
 
-        if self.coalesce:
-            key = (plan.signature(), eta, self.session.epoch)
-            task.flight_key = key
-            with self._flights_lock:
-                leader: Optional[Future] = self._flights.get(key)
-                if leader is None:
-                    self._flights.put(key, fut)
-            if leader is not None:
-                self.stats.inc(coalesce_hits=1)
-                leader.add_done_callback(
-                    lambda lf, t=task: self._resolve_from_leader(t, lf))
-                return task
+            if self.coalesce:
+                key = (plan.signature(), eta, self.session.epoch)
+                task.flight_key = key
+                with self._flights_lock:
+                    leader: Optional[Future] = self._flights.get(key)
+                    if leader is None:
+                        self._flights.put(key, fut)
+                if leader is not None:
+                    self.stats.inc(coalesce_hits=1)
+                    leader.add_done_callback(
+                        lambda lf, t=task: self._resolve_from_leader(t, lf))
+                    return task
 
-        with self._queue_cond:
-            self._queue.append(task)
-            depth = len(self._queue)
-            self._queue_cond.notify()
-        self.stats.imax(queue_depth_peak=depth)
-        return task
+            with self._queue_cond:
+                self._queue.append(task)
+                depth = len(self._queue)
+                self._queue_cond.notify()
+            self.stats.imax(queue_depth_peak=depth)
+            return task
 
     def query(self, plan: GridQuery, *, eta: Optional[int] = None,
               timeout: Optional[float] = None) -> Tuple[Any, RunReport]:
@@ -435,6 +418,8 @@ class GridFrontend:
             self._queue_cond.notify_all()
         self._scheduler.join(timeout=10.0)
         self._pool.shutdown(wait=True)
+        self._ready.put(None)
+        self._watcher.join(timeout=10.0)
         if self.session.fold_gate is self._installed_gate:
             self.session.fold_gate = None
 
@@ -509,8 +494,9 @@ class GridFrontend:
             if len(live) == 1:
                 t = live[0]
                 out = self._execute_with_retries(
-                    live, lambda: self._locked_exec(t.plan, t.eta))
+                    live, lambda: self._locked_exec(t.trace, t.plan, t.eta))
                 self._finish(t, out)
+                self._watch(out)
                 return
             # merged tick: one fused pass answers every plan in the group
             offsets: List[Tuple[_Task, int, int]] = []
@@ -521,21 +507,25 @@ class GridFrontend:
             merged = live[0].plan._fork(programs=programs)
             self.stats.inc(batch_merges=1, batched_queries=len(live))
             results, report = self._execute_with_retries(
-                live, lambda: self._locked_exec(merged, live[0].eta))
+                live, lambda: self._locked_exec(live[0].trace, merged,
+                                                live[0].eta))
             for t, off, k in offsets:
                 self._finish(t, (self._split(results, off, k), report))
+            self._watch((results, report))
         except BaseException as e:     # noqa: BLE001 — resolve every future
             for t in live:
                 self._fail(t, e)
         finally:
             self._exec_tls.tasks = None
 
-    def _locked_exec(self, plan: GridQuery,
+    def _locked_exec(self, trace: spans.QueryTrace, plan: GridQuery,
                      eta: Optional[int]) -> Tuple[Any, RunReport]:
-        with self._rwlock.read():
-            # one promotion sweep serves every coalesced member
-            self.session.prefetch_plan(plan)
-            return self.session._execute_plan(plan, eta=eta)
+        with self._rwlock.read(), spans.active(trace):
+            trace.mark_running(time.time_ns())
+            with spans.span("grid.execute"):
+                # one promotion sweep serves every coalesced member
+                self.session.prefetch_plan(plan)
+                return self.session._execute_plan(plan, eta=eta)
 
     def _execute_with_retries(self, live: List[_Task],
                               run: Callable[[], Tuple]) -> Tuple:
@@ -631,7 +621,6 @@ class GridFrontend:
         if not self._claim(task):
             return                # abandoned meanwhile: already settled
         self._breaker_ok(task)
-        self.stats.record_latency(time.monotonic() - task.t_submit)
         self.stats.inc(served=1)
         task.future.set_result(out)
 
@@ -650,6 +639,25 @@ class GridFrontend:
         timeout = timeout or isinstance(exc, QueryTimeoutError)
         self.stats.inc(failed=1, timeouts=1 if timeout else 0)
         task.future.set_exception(exc)
+
+    def _watch(self, out: Tuple[Any, RunReport]) -> None:
+        results, report = out
+        self._ready.put((report.trace, results))
+
+    def _watch_loop(self) -> None:
+        grouped = lambda x: isinstance(x, GroupedResult)  # noqa: E731
+        while True:
+            item = self._ready.get()
+            if item is None:
+                return
+            trace, results = item
+            leaves = [x.values if grouped(x) else x
+                      for x in jax.tree.leaves(results, is_leaf=grouped)]
+            try:
+                jax.block_until_ready(leaves)
+            except Exception:  # noqa: BLE001 — the answer's reader sees it
+                continue
+            trace.mark_ready(time.time_ns())
 
     # --- circuit breakers ---------------------------------------------
 

@@ -92,6 +92,7 @@ import numpy as np
 
 import jax
 
+from repro.core import spans
 from repro.core.balancer import (
     NodeSpec,
     allocation_imbalance,
@@ -200,13 +201,17 @@ class SessionMetrics(AtomicStats):
 
 @dataclasses.dataclass(frozen=True)
 class RunReport:
-    """Accounting for one executed plan (``run``/``run_where``/``collect``)."""
+    """Accounting for one executed plan (``run``/``run_where``/``collect``).
+
+    ``trace`` is the execution's timing record (mutable: the frontend
+    stamps its device wait after the report is built)."""
 
     epoch: int
     eta: int
     plan_cache_hit: bool
     mapreduce: Optional[MapReduceStats]   # None for pure retrieve plans
     query: Optional[QueryStats] = None
+    trace: Optional[spans.QueryTrace] = None
 
 
 class _SessionScheduler(GridScheduler):
@@ -880,7 +885,23 @@ class GridSession:
     def _execute_plan(
         self, plan: GridQuery, eta: Optional[int] = None
     ) -> Tuple[Any, RunReport]:
-        """Compile + execute a :class:`GridQuery` with all three pushdowns."""
+        """Compile + execute a :class:`GridQuery` with all three pushdowns.
+
+        The report carries the thread's active timing record; a call with
+        none active (not through :class:`GridFrontend`) is its own record,
+        rooted at a ``grid.execute`` span."""
+        trace = spans.current()
+        if trace is not None:
+            results, report = self._execute(plan, eta)
+        else:
+            with spans.active(spans.QueryTrace()) as trace, \
+                    spans.span("grid.execute"):
+                results, report = self._execute(plan, eta)
+        return results, dataclasses.replace(report, trace=trace)
+
+    def _execute(
+        self, plan: GridQuery, eta: Optional[int]
+    ) -> Tuple[Any, RunReport]:
         eta = int(eta or self.default_eta)
         self.metrics.inc(scans=1)
         if not plan.programs:
@@ -1026,50 +1047,51 @@ class GridSession:
         gain a leading group axis) in the same single pass per block —
         grouping never multiplies gathers or folds.
         """
-        cols = plan.compute_columns()
-        full = (plan.start is None and plan.stop is None
-                and plan.predicate is None)
-        if full:
-            mask = None
-            # the full-table work list is a pure function of the epoch
-            # (regions, row slices, owners, versions all mutate only
-            # through _advance_epoch), so the repeat-query hot path skips
-            # the per-region bisects entirely
-            fw = self._full_work
-            if fw is None or fw[0] != self._epoch:
-                fw = (self._epoch,
-                      self._plan_work(None, tuple(self.table.regions.regions)))
-                self._full_work = fw
-            work = fw[1]
-            n = self.table.num_rows
-            qstats = QueryStats(
-                rows_scanned=n, index_bytes_scanned=0,
-                payload_bytes_traversed=0, rows_selected=n,
-                regions_scanned=len(work), regions_pruned=0)
-        else:
-            mask, qstats, regions = self._scan_mask(plan)
-            work = self._plan_work(mask, regions)
+        with spans.span("grid.plan"):
+            cols = plan.compute_columns()
+            full = (plan.start is None and plan.stop is None
+                    and plan.predicate is None)
+            if full:
+                mask = None
+                # the full-table work list is a pure function of the
+                # epoch (regions, row slices, owners, versions all mutate
+                # only through _advance_epoch), so the repeat-query hot
+                # path skips the per-region bisects entirely
+                fw = self._full_work
+                if fw is None or fw[0] != self._epoch:
+                    fw = (self._epoch, self._plan_work(
+                        None, tuple(self.table.regions.regions)))
+                    self._full_work = fw
+                work = fw[1]
+                n = self.table.num_rows
+                qstats = QueryStats(
+                    rows_scanned=n, index_bytes_scanned=0,
+                    payload_bytes_traversed=0, rows_selected=n,
+                    regions_scanned=len(work), regions_pruned=0)
+            else:
+                mask, qstats, regions = self._scan_mask(plan)
+                work = self._plan_work(mask, regions)
 
-        # the plan's lineage signature: region content versions + row-mask
-        # signatures — shared by the group-mapping memo and every column's
-        # result-cache key
-        work_sig = tuple(
-            (w.region.signature, self.blocks.version_of(w.region.rid),
-             w.mask_sig) for w in work)
+            # the plan's lineage signature: region content versions +
+            # row-mask signatures — shared by the group-mapping memo and
+            # every column's result-cache key
+            work_sig = tuple(
+                (w.region.signature, self.blocks.version_of(w.region.rid),
+                 w.mask_sig) for w in work)
 
-        group: Optional[_GroupInfo] = None
-        if plan.group_key is not None:
-            group = self._group_info(plan, mask, work_sig)
-            program = GroupedProgram(program, group.num_groups)
-            # the key column is scanned like any index column
+            group: Optional[_GroupInfo] = None
+            if plan.group_key is not None:
+                group = self._group_info(plan, mask, work_sig)
+                program = GroupedProgram(program, group.num_groups)
+                # the key column is scanned like any index column
+                qstats = dataclasses.replace(
+                    qstats, num_groups=group.num_groups,
+                    index_bytes_scanned=qstats.index_bytes_scanned
+                    + qstats.rows_scanned * group.row_nbytes)
+            per_row = sum(self.table.column_spec(f, q).row_nbytes
+                          for f, q in cols)
             qstats = dataclasses.replace(
-                qstats, num_groups=group.num_groups,
-                index_bytes_scanned=qstats.index_bytes_scanned
-                + qstats.rows_scanned * group.row_nbytes)
-        per_row = sum(self.table.column_spec(f, q).row_nbytes
-                      for f, q in cols)
-        qstats = dataclasses.replace(
-            qstats, payload_bytes_moved=qstats.rows_selected * per_row)
+                qstats, payload_bytes_moved=qstats.rows_selected * per_row)
 
         outcomes = [
             self._fold_column(program, eta, mask, work, work_sig, f, q,
@@ -1136,12 +1158,13 @@ class GridSession:
     ) -> _ColumnOutcome:
         """Resolve one computed column: result cache → compact → blockwise."""
         spec = self.table.column_spec(family, qualifier)
-        result_key = (
-            "fold", program.cache_key(), family, qualifier, int(eta),
-            self._mesh_shape(), group.sig if group is not None else "",
-            work_sig,
-        )
-        entry = self._results.get(result_key)
+        with spans.span("grid.plan"):
+            result_key = (
+                "fold", program.cache_key(), family, qualifier, int(eta),
+                self._mesh_shape(), group.sig if group is not None else "",
+                work_sig,
+            )
+            entry = self._results.get(result_key)
         if entry is not None:
             entry.last_used = self._epoch
             self.metrics.inc(partials_reused=entry.partials_total)
@@ -1293,7 +1316,8 @@ class GridSession:
             pkey = self.blocks.partial_key(
                 w.region, family, qualifier, prog_key, w.mask_sig, eta,
                 group_sig=gsig, impl=impl_sig)
-            partial = self.blocks.get_partial(pkey)
+            with spans.span("blockstore.fetch"):
+                partial = self.blocks.get_partial(pkey)
             if partial is not None:
                 p_reused += 1
                 acct.total += 1
@@ -1326,9 +1350,10 @@ class GridSession:
                     rounds[w.owner] = rounds.get(w.owner, 0) + c
             partials.append(partial)
             owners.append(w.owner)
-        result = self.engine.merge_finalize(program, partials,
-                                            spec.shape, spec.dtype,
-                                            owners=owners)
+        with spans.span("merge.dispatch"):
+            result = self.engine.merge_finalize(program, partials,
+                                                spec.shape, spec.dtype,
+                                                owners=owners)
         self._results.put(result_key, _ResultEntry(
             result=result, partials_total=p_total, blocks_total=acct.total,
             region_ids=frozenset(w.region.rid for w in work),
@@ -1368,8 +1393,9 @@ class GridSession:
         the partial under ``pkey``.  Returns ``(partial, block, reused,
         gathered)`` so the caller (or a coalescing fold gate's followers)
         can account the fetch classification exactly once."""
-        blk, reused, gathered = self._fetch_block(
-            w.region, family, qualifier, owner=w.owner)
+        with spans.span("blockstore.fetch"):
+            blk, reused, gathered = self._fetch_block(
+                w.region, family, qualifier, owner=w.owner)
         base_mask = None if w.mask_sig == "full" else mask[w.rows]
         gid_base = None
         if group is not None:
@@ -1405,10 +1431,11 @@ class GridSession:
                     g2 = np.zeros(src_rows, np.int32)
                     g2[:b.rows] = gid_arr
                     gid_arr = g2
-            return self.engine.fold_block(
-                program, src, bmask, eta, spec.shape, spec.dtype,
-                gids=gid_arr, num_groups=n_groups,
-                owner=w.owner if use_device else None)
+            with spans.span("fold.dispatch", rid=w.region.rid):
+                return self.engine.fold_block(
+                    program, src, bmask, eta, spec.shape, spec.dtype,
+                    gids=gid_arr, num_groups=n_groups,
+                    owner=w.owner if use_device else None)
 
         def run(b: DeviceBlock, force_host: bool = False):
             if self.faults is None:
@@ -1432,7 +1459,8 @@ class GridSession:
             gathered = gathered or regath
             blk = hblk
             partial = run(hblk, force_host=True)
-        self.blocks.put_partial(pkey, partial)
+        with spans.span("blockstore.fetch"):
+            self.blocks.put_partial(pkey, partial)
         return partial, blk, reused, gathered
 
     def _scan_mask(
@@ -1478,7 +1506,8 @@ class GridSession:
         ``transferred``/``gather_count`` a fresh table read (host-side;
         nothing ships to a device on this path).
         """
-        mask, qstats, regions = self._scan_mask(plan)
+        with spans.span("grid.plan"):
+            mask, qstats, regions = self._scan_mask(plan)
         sel = np.nonzero(mask)[0]
         acct = _BlockAccount()
         cols: Dict[str, np.ndarray] = {}
@@ -1592,24 +1621,26 @@ class GridSession:
         Transient injected transfer faults retry here under the session
         policy; :class:`DeviceLostError` propagates to
         :meth:`_fetch_block`, which owns quarantine + host degrade."""
-        if host.ndim > 2:
-            host = host.reshape(host.shape[0], int(np.prod(host.shape[1:])))
-        bucket = self.engine.bucket_rows(len(host))
-        if bucket != len(host):
-            host = np.concatenate(
-                [host, np.zeros((bucket - len(host),) + host.shape[1:],
-                                host.dtype)])
-        dev = None if owner_index is None else self._devices[owner_index]
-        if self.faults is None:
-            return jax.device_put(host, dev)
+        with spans.span("blockstore.commit"):
+            if host.ndim > 2:
+                host = host.reshape(host.shape[0],
+                                    int(np.prod(host.shape[1:])))
+            bucket = self.engine.bucket_rows(len(host))
+            if bucket != len(host):
+                host = np.concatenate(
+                    [host, np.zeros((bucket - len(host),) + host.shape[1:],
+                                    host.dtype)])
+            dev = None if owner_index is None else self._devices[owner_index]
+            if self.faults is None:
+                return jax.device_put(host, dev)
 
-        def attempt():
-            self.faults.fire("device_put", device=owner_index)
-            return jax.device_put(host, dev)
+            def attempt():
+                self.faults.fire("device_put", device=owner_index)
+                return jax.device_put(host, dev)
 
-        return self.retry_policy.call(
-            attempt, key=f"device_put:{owner_index}",
-            on_retry=lambda e, a: self.blocks.stats.inc(retries=1))
+            return self.retry_policy.call(
+                attempt, key=f"device_put:{owner_index}",
+                on_retry=lambda e, a: self.blocks.stats.inc(retries=1))
 
     # ------------------------------------------------------------------
     # helpers / diagnostics
@@ -1698,4 +1729,8 @@ class GridSession:
             f"({m.rows_gathered} rows gathered, "
             f"{m.pushdown_rows_gathered} pushdown rows)",
         ]
+        # process-wide: every session and frontend of the process
+        lines += [f"  span {name}: {st.count} in {st.total_s:.6f} s "
+                  f"(self {st.self_s:.6f} s, {st.compiles} compiles)"
+                  for name, st in sorted(spans.totals().items())]
         return "\n".join(lines)

@@ -30,6 +30,7 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple, Uni
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.regions import (
     ConstantSizeSplitPolicy,
     Region,
@@ -258,61 +259,65 @@ class TensorTable:
             raise ValueError(f"unknown on_duplicate mode {on_duplicate!r}")
         if not len(rowkeys):
             return 0
-        new_keys = np.array([_as_key(k) for k in rowkeys], dtype="S64")
-        if len(np.unique(new_keys)) != len(new_keys):
-            raise ValueError("duplicate rowkeys within one upload batch")
+        with spans.span("table.upload"):
+            new_keys = np.array([_as_key(k) for k in rowkeys], dtype="S64")
+            if len(np.unique(new_keys)) != len(new_keys):
+                raise ValueError("duplicate rowkeys within one upload batch")
 
-        # validate payloads against the schema
-        arrays: Dict[Tuple[str, str], np.ndarray] = {}
-        for fam in self.families.values():
-            fam_data = data.get(fam.name)
-            if fam_data is None:
-                raise ValueError(f"missing column family {fam.name!r} in upload")
-            for col in fam.columns:
-                if col.qualifier not in fam_data:
-                    raise ValueError(f"missing column {fam.name}:{col.qualifier}")
-                arr = np.asarray(fam_data[col.qualifier], dtype=col.dtype)
-                want = (len(new_keys),) + col.shape
-                if arr.shape != want:
+            # validate payloads against the schema
+            arrays: Dict[Tuple[str, str], np.ndarray] = {}
+            for fam in self.families.values():
+                fam_data = data.get(fam.name)
+                if fam_data is None:
                     raise ValueError(
-                        f"{fam.name}:{col.qualifier} shape {arr.shape} != {want}"
-                    )
-                arrays[(fam.name, col.qualifier)] = arr
+                        f"missing column family {fam.name!r} in upload")
+                for col in fam.columns:
+                    if col.qualifier not in fam_data:
+                        raise ValueError(
+                            f"missing column {fam.name}:{col.qualifier}")
+                    arr = np.asarray(fam_data[col.qualifier], dtype=col.dtype)
+                    want = (len(new_keys),) + col.shape
+                    if arr.shape != want:
+                        raise ValueError(
+                            f"{fam.name}:{col.qualifier} shape {arr.shape} != {want}"
+                        )
+                    arrays[(fam.name, col.qualifier)] = arr
 
-        # split batch into updates (existing keys) and inserts
-        pos = np.searchsorted(self._keys, new_keys, side="left")
-        exists = self.existing_mask(rowkeys)
+            # split batch into updates (existing keys) and inserts
+            pos = np.searchsorted(self._keys, new_keys, side="left")
+            exists = self.existing_mask(rowkeys)
 
-        written = 0
-        if exists.any():
-            if on_duplicate == "error":
-                dups = [k.decode(errors="replace") for k in new_keys[exists]]
-                raise KeyError(f"rowkeys already uploaded: {dups}")
-            if on_duplicate == "overwrite":
-                upd = np.nonzero(exists)[0]
-                tgt = pos[upd]
+            written = 0
+            if exists.any():
+                if on_duplicate == "error":
+                    dups = [k.decode(errors="replace")
+                            for k in new_keys[exists]]
+                    raise KeyError(f"rowkeys already uploaded: {dups}")
+                if on_duplicate == "overwrite":
+                    upd = np.nonzero(exists)[0]
+                    tgt = pos[upd]
+                    for kq, arr in arrays.items():
+                        self._data[kq][tgt] = arr[upd]
+                    written += len(upd)
+                # else "skip": keep the stored rows (interface semantics)
+
+            ins = np.nonzero(~exists)[0]
+            if len(ins):
+                order = ins[np.argsort(new_keys[ins], kind="stable")]
+                ins_keys = new_keys[order]
+                ins_pos = np.searchsorted(self._keys, ins_keys, side="left")
+                self._keys = np.insert(self._keys, ins_pos, ins_keys)
                 for kq, arr in arrays.items():
-                    self._data[kq][tgt] = arr[upd]
-                written += len(upd)
-            # else "skip": keep the stored rows (interface semantics)
+                    self._data[kq] = np.insert(
+                        self._data[kq], ins_pos, arr[order], axis=0
+                    )
+                written += len(ins)
 
-        ins = np.nonzero(~exists)[0]
-        if len(ins):
-            order = ins[np.argsort(new_keys[ins], kind="stable")]
-            ins_keys = new_keys[order]
-            ins_pos = np.searchsorted(self._keys, ins_keys, side="left")
-            self._keys = np.insert(self._keys, ins_pos, ins_keys)
-            for kq, arr in arrays.items():
-                self._data[kq] = np.insert(
-                    self._data[kq], ins_pos, arr[order], axis=0
-                )
-            written += len(ins)
-
-        events = self.regions.maybe_split(self._keys, self.row_bytes())
-        self.split_log.extend(events)
-        if written:
-            self.mutation_count += 1
-        return written
+            events = self.regions.maybe_split(self._keys, self.row_bytes())
+            self.split_log.extend(events)
+            if written:
+                self.mutation_count += 1
+            return written
 
     def select_keys(
         self,

@@ -503,15 +503,6 @@ class TestThreadSafetySubstrate:
 
 
 class TestFrontendStats:
-    def test_latency_percentiles(self):
-        stats = FrontendStats()
-        assert stats.latency_percentiles() == (0.0, 0.0)
-        for ms in range(1, 101):
-            stats.record_latency(ms / 1000.0)
-        p50, p99 = stats.latency_percentiles()
-        assert 0.045 <= p50 <= 0.055
-        assert 0.095 <= p99 <= 0.100
-
     def test_queue_depth_peak_observed(self):
         s = make_session()
         plan_a = s.scan().map(MeanProgram()).reduce()

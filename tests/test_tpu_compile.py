@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from bench.tracefile import KERNEL_RE
 from repro.core.mapreduce import MapReduceEngine
 from repro.core.stats import FusedProgram, GroupedProgram, \
     HistogramProgram, MeanProgram, VarianceProgram
@@ -100,6 +101,24 @@ def test_engine_grouped_fold_at_full_width_block(one_chip):
                          _spec((R,), jnp.int32, one_chip))
     assert arg + temp < HBM_BYTES
     assert temp < R * FEATURES * 4, (arg, temp)
+
+
+def test_engine_fold_kernel_keeps_the_name_the_benchmark_reads(one_chip):
+    """The benchmark finds the fold kernel's device time by its HLO
+    instruction (``bench.tracefile.KERNEL_RE``), a name the jitted
+    ``fused_fold_pallas`` wrapper gives the custom call: the engine's
+    per-block executable for a fused mean + variance subset fold must
+    still carry it."""
+    engine = MapReduceEngine(make_mesh((1,), ("data",)))
+    program = FusedProgram((MeanProgram(), VarianceProgram()))
+    fold = engine._pallas_fold_fn(program, BLOCK_ROWS, MNI_SHAPE,
+                                  jnp.float32, masked=True)
+    text = jax.jit(fold).lower(
+        _spec((BLOCK_ROWS, FEATURES), jnp.float32, one_chip),
+        _spec((BLOCK_ROWS,), jnp.bool_, one_chip)).compile().as_text()
+    ops = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
+    assert any(KERNEL_RE.match(op) for op in ops), [
+        op[:80] for op in ops if "custom-call" in op]
 
 
 @pytest.mark.parametrize("program", [
