@@ -187,6 +187,8 @@ class SessionMetrics(AtomicStats):
     plan_misses: int = 0
     partials_folded: int = 0        # per-block folds executed (map tasks run)
     partials_reused: int = 0        # per-block partials served from the cache
+    kernel_folds_rowsum: int = 0    # folds on the fused kernel's row sum
+    kernel_folds_onehot: int = 0    # ... and on its one-hot contraction
     rows_folded: int = 0            # payload rows read by per-block folds
     rows_gathered: int = 0          # payload rows copied into device blocks
     pushdown_rows_gathered: int = 0  # payload rows gathered by pruned scans
@@ -1134,7 +1136,11 @@ class GridSession:
             shuffle_bytes=sum(o.mr.shuffle_bytes for o in outcomes),
             rounds=max(o.mr.rounds for o in outcomes),
             chunks=sum(o.mr.chunks for o in outcomes),
-            chunk_size=eta)
+            chunk_size=eta,
+            kernel_folds_rowsum=sum(o.mr.kernel_folds_rowsum
+                                    for o in outcomes),
+            kernel_folds_onehot=sum(o.mr.kernel_folds_onehot
+                                    for o in outcomes))
 
         def _wrap(o: _ColumnOutcome) -> Any:
             if group is not None:
@@ -1285,6 +1291,7 @@ class GridSession:
         # identical to pre-kernel sessions).
         fold_impl = self.engine.fold_path(program, spec.dtype, n_groups)
         impl_sig = fold_impl if fold_impl != "xla" else ""
+        schedule = self.engine.kernel_schedule(program, spec.dtype, n_groups)
         acct = _BlockAccount()
         if (self._tiering and self._devices is not None
                 and self.blocks.prefetch_enabled):
@@ -1306,6 +1313,7 @@ class GridSession:
         partials: List[Any] = []
         owners: List[Optional[int]] = []
         p_total = p_reused = rows_folded = local_rows = chunks = 0
+        kernel_folds = {"rowsum": 0, "onehot": 0}
         rounds: Dict[Optional[int], int] = {}
         for w in work:
             if w.selected == 0:
@@ -1347,6 +1355,8 @@ class GridSession:
                     local_rows += w.selected
                     c = -(-blk.rows // eta)
                     chunks += c
+                    if schedule:
+                        kernel_folds[schedule] += 1
                     rounds[w.owner] = rounds.get(w.owner, 0) + c
             partials.append(partial)
             owners.append(w.owner)
@@ -1364,7 +1374,9 @@ class GridSession:
             rows_folded=rows_folded, rows_gathered=acct.rows_gathered,
             pushdown_rows_gathered=(acct.rows_gathered
                                     if mask is not None else 0),
-            payload_gathers=1 if acct.gathered else 0)
+            payload_gathers=1 if acct.gathered else 0,
+            kernel_folds_rowsum=kernel_folds["rowsum"],
+            kernel_folds_onehot=kernel_folds["onehot"])
 
         pb = self.engine.partial_nbytes(program, spec.shape, spec.dtype)
         # local_* use the layout path's logical convention (selected rows ×
@@ -1376,7 +1388,9 @@ class GridSession:
             shuffle_bytes=pb * len(partials),
             rounds=max(rounds.values(), default=0),
             chunks=chunks,
-            chunk_size=eta)
+            chunk_size=eta,
+            kernel_folds_rowsum=kernel_folds["rowsum"],
+            kernel_folds_onehot=kernel_folds["onehot"])
         return _ColumnOutcome(
             result=result, hit=False, gather_path="blocks",
             merge_path=self.engine.last_merge_path, acct=acct,
@@ -1722,7 +1736,9 @@ class GridSession:
             f"engine compiles: {self.engine.compile_count}",
             f"  folds: {m.partials_folded} block partials folded "
             f"({m.rows_folded} rows), {m.partials_reused} reused, "
-            f"{m.compact_scans} compact one-shots",
+            f"{m.compact_scans} compact one-shots; on the fused kernel "
+            f"{m.kernel_folds_rowsum} row-sum, {m.kernel_folds_onehot} "
+            f"one-hot",
             f"  blocks: {self.blocks.describe()}",
             f"  queries: {m.scans} plans executed, {m.programs_fused} "
             f"programs fused, {m.payload_gathers} payload gather passes "

@@ -221,6 +221,11 @@ class MapReduceStats:
     rounds: int                   # lockstep map rounds (wall-clock proxy)
     chunks: int                   # Σ real chunks (#job; resource proxy)
     chunk_size: int
+    # block folds run on the fused kernel, by its schedule (see
+    # MapReduceEngine.kernel_schedule): the ungrouped VPU row sum and the
+    # grouped one-hot MXU contraction
+    kernel_folds_rowsum: int = 0
+    kernel_folds_onehot: int = 0
 
 
 class MapReduceEngine:
@@ -448,6 +453,16 @@ class MapReduceEngine:
         if max(1, int(num_groups)) > max_groups_for_vmem(names=names):
             return "xla"
         return "pallas"
+
+    def kernel_schedule(self, program: MapReduceProgram, dtype,
+                        num_groups: int = 0) -> str:
+        """The fused kernel's schedule for this fold signature:
+        ``"rowsum"`` (one group) or ``"onehot"`` (grouped); ``""`` when
+        :meth:`fold_path` sends it to the XLA fold."""
+        if self.fold_path(program, dtype, num_groups) != "pallas":
+            return ""
+        from repro.kernels.fused_fold.ops import fold_schedule
+        return fold_schedule(num_groups)
 
     def _pallas_fold_fn(self, program: MapReduceProgram, rows: int,
                         row_shape, dtype, masked: bool, groups: int = 0):

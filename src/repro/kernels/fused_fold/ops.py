@@ -1,7 +1,8 @@
 """Public op: fused grouped power-sum fold over a block of rows.
 
-Handles arbitrary row shapes (flattens features), pads groups to a sublane
-multiple (padded groups receive no rows), dispatches to the Pallas kernel,
+Handles arbitrary row shapes (flattens features), pads groups of a grouped
+fold to a sublane multiple (padded groups receive no rows; an ungrouped
+fold's pool stays one row), dispatches to the Pallas kernel,
 and exposes the analytic cost and VMEM-budget helpers the engine's
 ``fold_path`` dispatch and the roofline probe consult.  Rows and features
 are never padded: the kernel's grid covers ragged edges itself, so a
@@ -30,6 +31,9 @@ from repro.kernels.fused_fold.kernel import (
     fused_fold_pallas,
 )
 
+#: the kernel's two schedules, named by how a fold combines its rows
+ROWSUM, ONEHOT = "rowsum", "onehot"
+
 
 def canonical_names(names: Tuple[str, ...]) -> Tuple[str, ...]:
     """Validate and order accumulator names along ``ACC_ORDER``."""
@@ -42,8 +46,18 @@ def canonical_names(names: Tuple[str, ...]) -> Tuple[str, ...]:
     return tuple(n for n in ACC_ORDER if n in set(names))
 
 
-def _pad_groups(num_groups: int) -> int:
-    """Groups padded to an fp32 sublane multiple (min tile is 8 rows)."""
+def fold_schedule(num_groups: int) -> str:
+    """Which schedule of the kernel folds ``num_groups`` groups: the VPU
+    row sum for one group, the one-hot MXU contraction for more."""
+    return ROWSUM if max(1, int(num_groups)) == 1 else ONEHOT
+
+
+def _pool_groups(num_groups: int) -> int:
+    """Rows of the pool the kernel writes: one for the row-sum schedule;
+    otherwise the groups padded to an fp32 sublane multiple (min tile is
+    8 rows)."""
+    if fold_schedule(num_groups) == ROWSUM:
+        return 1
     return max(8, -(-int(num_groups) // 8) * 8)
 
 
@@ -58,7 +72,7 @@ def fused_fold(
     num_groups: int = 1,
     names: Tuple[str, ...] = ACC_ORDER,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    block_features: int = DEFAULT_BLOCK_FEATURES,
+    block_features: Optional[int] = None,   # None: the schedule's own
     interpret: bool = False,         # True only for CPU runs and tests
 ) -> Dict[str, jax.Array]:
     """-> ``{name: acc}``: count ``[G]``, s_k ``[G, *feature_shape]`` fp32.
@@ -79,7 +93,7 @@ def fused_fold(
     g = (jnp.zeros((R,), jnp.int32) if gids is None
          else gids.astype(jnp.int32))
 
-    Gp = _pad_groups(G)
+    Gp = _pool_groups(G)
 
     outs = fused_fold_pallas(x, g, m, names, Gp, block_rows, block_features,
                              interpret=interpret)
@@ -99,23 +113,27 @@ def fused_fold(
 def kernel_hbm_bytes(rows: int, features: int, itemsize: int,
                      names: Tuple[str, ...], num_groups: int = 1) -> int:
     """HBM bytes one kernel launch moves: the payload ONCE, the per-row
-    mask/gid sidecars, and the accumulator write-back.  This is the
-    one-pass contract the bench checks XLA's measured fold bytes against."""
+    mask/gid sidecars, and the accumulator write-back (one row a power
+    for an ungrouped fold, the sublane-padded groups otherwise).  This is
+    the one-pass contract the bench checks XLA's measured fold bytes
+    against."""
     names = canonical_names(names)
-    G = _pad_groups(max(1, num_groups))
+    G = _pool_groups(num_groups)
     out = sum(G * 4 if n == "count" else G * features * 4 for n in names)
     return rows * features * itemsize + rows * (4 + 4) + out
 
 
 def kernel_flops(rows: int, features: int,
                  names: Tuple[str, ...], num_groups: int = 1) -> int:
-    """FLOPs per launch: one [BR,G]×[BR,X] contraction per accumulator
-    (2·R·X·G each) plus the elementwise power raises and weight build."""
+    """FLOPs per launch: per accumulator one [BR,G]×[BR,X] contraction
+    (2·R·X·G each), or for an ungrouped fold one add a row and column
+    (R·X each), plus the elementwise power raises and weight build."""
     names = canonical_names(names)
-    G = _pad_groups(max(1, num_groups))
+    G = _pool_groups(num_groups)
+    per_elem = 1 if G == 1 else 2 * G
     f = 0
     for n in names:
-        f += 2 * rows * G * (1 if n == "count" else features)
+        f += per_elem * rows * (1 if n == "count" else features)
     n_pows = sum(1 for n in names if n != "count")
     # x², x³, x⁴ elementwise products + mask/where + one-hot compare
     f += rows * features * max(0, n_pows - 1)

@@ -43,9 +43,11 @@ from repro.core.table import ColumnSpec, make_mip_table
 from repro.kernels.fused_fold import (
     fused_fold,
     fused_fold_numpy,
+    kernel_flops,
     kernel_hbm_bytes,
     max_groups_for_vmem,
 )
+from repro.kernels.fused_fold.kernel import ACC_ORDER, rowsum_tiles
 from repro.utils import make_mesh
 
 rng = np.random.default_rng(421)
@@ -187,6 +189,96 @@ class TestKernelVsOracle:
 
 
 # ----------------------------------------------------------------------
+# the G = 1 schedule: VPU row sum over wide feature tiles, [1, F] pool
+# ----------------------------------------------------------------------
+
+WIDE_F = 40_000                # several 1024-lane tiles, ragged last one
+
+
+class TestRowSumSchedule:
+    @pytest.mark.parametrize("real,names,block_features", [
+        (26, ("count", "s1"), 1024),
+        (30, ("count", "s1", "s2"), 1024),
+        (32, ACC_ORDER, 1024),
+        (26, ACC_ORDER, None),
+        (32, ("count", "s1", "s2"), None),
+    ])
+    def test_pad_rows_masked_wide_ragged_tiles(self, real, names,
+                                               block_features):
+        """A 32-row block holding ``real`` rows, as the engine commits a
+        region: the pad rows hold NaN/Inf and are masked off, F is not a
+        multiple of 128 and spans several feature tiles."""
+        r = np.random.default_rng(real)
+        x = r.normal(size=(32, WIDE_F)).astype(np.float32)
+        x[real:] = np.nan
+        x[real:, ::3] = np.inf
+        m = r.random(32) > 0.4
+        m[real:] = False
+        got = fused_fold(jnp.asarray(x), jnp.asarray(m), names=names,
+                         block_features=block_features, interpret=True)
+        want = fused_fold_numpy(x, m, names=names)
+        assert got["s1"].shape == (1, WIDE_F)
+        for a in got.values():
+            assert bool(jnp.isfinite(a).all())
+        assert_pool_close(got, want)
+        np.testing.assert_array_equal(np.asarray(got["count"]),
+                                      want["count"])
+
+    @pytest.mark.parametrize("names", [("count", "s1"),
+                                       ("count", "s1", "s2"), ACC_ORDER])
+    def test_mask_none(self, names):
+        x = rng.normal(size=(32, WIDE_F)).astype(np.float32)
+        got = fused_fold(jnp.asarray(x), None, names=names,
+                         block_features=2048, interpret=True)
+        assert_pool_close(got, fused_fold_numpy(x, names=names))
+        assert float(got["count"][0]) == 32
+
+    @pytest.mark.parametrize("R,block_rows", [(300, 256), (40, 16)])
+    def test_row_tiles_accumulate(self, R, block_rows):
+        """More rows than one row tile: the pool accumulates across the
+        row sweep, the ragged last tile's rows masked by their index."""
+        x = rng.normal(size=(R, 700)).astype(np.float32)
+        m = rng.random(R) > 0.3
+        got = fused_fold(jnp.asarray(x), jnp.asarray(m),
+                         block_rows=block_rows, interpret=True)
+        want = fused_fold_numpy(x, m)
+        assert_pool_close(got, want)
+        np.testing.assert_array_equal(np.asarray(got["count"]),
+                                      want["count"])
+
+    @pytest.mark.parametrize("names", [("count", "s1"), ACC_ORDER])
+    def test_nan_inf_in_masked_rows_never_poison(self, names):
+        x = rng.normal(size=(24, 300)).astype(np.float32)
+        m = np.ones(24, bool)
+        m[[0, 11, 23]] = False
+        x[0] = np.nan
+        x[11] = np.inf
+        x[23, ::2] = -np.inf
+        got = fused_fold(jnp.asarray(x), jnp.asarray(m), names=names,
+                         block_features=128, interpret=True)
+        for n, a in got.items():
+            assert bool(jnp.isfinite(a).all()), n
+        assert_pool_close(got, fused_fold_numpy(x, m, names=names))
+
+    @pytest.mark.parametrize("rows,features,itemsize,want", [
+        (32, 7_221_032, 4, (32, 32768, 1024)),     # a 1 mm block: 4 MiB
+        (32, 7_221_032, 2, (32, 65536, 1024)),     # bf16 rows: 4 MiB too
+        (1, 7_221_032, 4, (1, 131072, 4096)),      # rows pad to 8
+        (300, 4096, 4, (256, 4096, 128)),
+        (13, 5, 4, (13, 5, 5)),                    # narrower than a chunk
+        (32, WIDE_F, 4, (32, 32768, 1024)),     # 2 tiles, the last ragged
+    ])
+    def test_tiles_sized_by_bytes(self, rows, features, itemsize, want):
+        assert rowsum_tiles(rows, features, itemsize) == want
+
+    def test_explicit_tile_must_be_lane_aligned(self):
+        with pytest.raises(ValueError):
+            rowsum_tiles(32, WIDE_F, 4, block_features=1000)
+        assert rowsum_tiles(32, WIDE_F, 4, block_features=1536) == (
+            32, 1536, 768)
+
+
+# ----------------------------------------------------------------------
 # engine dispatch: eligibility, fallback, executable keying
 # ----------------------------------------------------------------------
 
@@ -305,6 +397,44 @@ class TestEngineDifferential:
                 np.asarray(a, np.float64), np.asarray(b, np.float64),
                 rtol=1e-4, atol=1e-3),
             results["pallas"], results["xla"])
+
+
+    @pytest.mark.parametrize("masked", [True, False])
+    def test_one_group_grouped_program_takes_the_row_sum(self, masked):
+        """A one-group ``GroupedProgram`` folds on the row-sum schedule
+        (gid 0 for every row) and agrees with the XLA fold, pad rows of
+        the pow2 bucket included."""
+        program = GroupedProgram(FusedProgram(CSE_MEMBERS), num_groups=1)
+        blocks = [rng.normal(size=(r,) + PAYLOAD).astype(np.float32)
+                  for r in (26, 30, 32, 5)]
+        masks = [rng.random(len(b)) > 0.3 if masked else None
+                 for b in blocks]
+        results = {}
+        for impl in ("pallas", "xla"):
+            eng = interp_engine(fold_impl=impl)
+            assert eng.kernel_schedule(program, np.float32, 1) == (
+                "rowsum" if impl == "pallas" else "")
+            ps = [eng.fold_block(
+                program, jnp.asarray(b),
+                None if m is None else jnp.asarray(m), 4, PAYLOAD,
+                np.float32, gids=jnp.zeros(len(b), jnp.int32), num_groups=1)
+                for b, m in zip(blocks, masks)]
+            results[impl] = eng.merge_finalize(program, ps, PAYLOAD,
+                                               np.float32)
+            assert eng.fold_path_counts[impl] == len(blocks)
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(
+                np.asarray(a, np.float64), np.asarray(b, np.float64),
+                rtol=1e-4, atol=1e-3),
+            results["pallas"], results["xla"])
+
+    def test_kernel_schedule_by_group_count(self):
+        eng = interp_engine()
+        fused = FusedProgram(CSE_MEMBERS)
+        assert eng.kernel_schedule(fused, np.float32, 0) == "rowsum"
+        assert eng.kernel_schedule(
+            GroupedProgram(fused, num_groups=2), np.float32, 2) == "onehot"
+        assert eng.kernel_schedule(HistogramProgram(), np.float32) == ""
 
 
 # ----------------------------------------------------------------------
@@ -471,3 +601,95 @@ class TestCostModel:
         full = max_groups_for_vmem()
         assert full > 0
         assert max_groups_for_vmem(("count", "s1")) > full
+
+    @pytest.mark.parametrize("names", [("count", "s1"),
+                                       ("count", "s1", "s2"), ACC_ORDER])
+    def test_ungrouped_write_back_is_unpadded(self, names):
+        """The row-sum schedule writes one pool row a power: the
+        write-back is ``names × F × 4`` bytes (4 for the count), where a
+        grouped fold writes its sublane-padded 8 rows."""
+        R, F = 32, 182 * 218 * 182
+        sidecars = R * F * 4 + R * (4 + 4)
+        row = sum(4 if n == "count" else F * 4 for n in names)
+        assert kernel_hbm_bytes(R, F, 4, names, num_groups=1) \
+            - sidecars == row
+        assert kernel_hbm_bytes(R, F, 4, names, num_groups=2) \
+            - sidecars == 8 * row
+
+    def test_ungrouped_flops_are_a_row_sum(self):
+        R, F = 32, 1000
+        names = ("count", "s1", "s2")
+        # one add a row and column per accumulator, the square, the mask
+        assert kernel_flops(R, F, names, num_groups=1) == \
+            R + 2 * R * F + R * F + R * F + R
+        assert kernel_flops(R, F, names, num_groups=2) == \
+            2 * 8 * (R + 2 * R * F) + R * F + R * F + R * 8
+
+
+# ----------------------------------------------------------------------
+# kernel folds by schedule: per-execution counters and the bench reader
+# ----------------------------------------------------------------------
+
+class TestKernelFoldCounters:
+    def ungrouped(self, s):
+        return (s.scan().select("img:data")
+                .where(lambda c: c["age"] > 30.0, ["age"])
+                .map(MeanProgram()).map(VarianceProgram()).reduce())
+
+    def grouped(self, s):
+        return (s.scan().select("img:data").group_by("idx:site")
+                .map(MeanProgram()).reduce())
+
+    def test_run_reports_count_folds_by_schedule(self):
+        s = pallas_session(make_table())
+        _, rep = self.ungrouped(s).collect()
+        folded = rep.query.partials_total - rep.query.partials_reused
+        assert folded > 0
+        assert rep.mapreduce.kernel_folds_rowsum == folded
+        assert rep.mapreduce.kernel_folds_onehot == 0
+        _, rep_g = self.grouped(s).collect()
+        folded_g = rep_g.query.partials_total - rep_g.query.partials_reused
+        assert rep_g.mapreduce.kernel_folds_onehot == folded_g > 0
+        assert rep_g.mapreduce.kernel_folds_rowsum == 0
+        # a repeat is served from the result cache: no fold at all
+        _, again = self.ungrouped(s).collect()
+        assert again.mapreduce.kernel_folds_rowsum == 0
+        m = s.metrics
+        assert (m.kernel_folds_rowsum, m.kernel_folds_onehot) == (
+            folded, folded_g)
+        assert (f"on the fused kernel {folded} row-sum, {folded_g} one-hot"
+                in s.describe())
+
+    def test_xla_folds_count_on_neither_schedule(self):
+        s = GridSession(make_table(), default_eta=4, fold_impl="xla")
+        _, rep = self.ungrouped(s).collect()
+        assert rep.query.partials_total > 0
+        assert rep.mapreduce.kernel_folds_rowsum == 0
+        assert rep.mapreduce.kernel_folds_onehot == 0
+
+    def test_bench_reader_share(self):
+        """``bench/metrics/fold.rowsum_share.py``: the row-sum share of
+        the window's kernel folds, a report shared by coalesced queries
+        counted once; nothing to read without the counters."""
+        import types
+
+        from bench.harness import load_metric
+        from repro.core.mapreduce import MapReduceStats
+
+        read = load_metric("fold.rowsum_share")
+
+        def report(rowsum, onehot):
+            return types.SimpleNamespace(mapreduce=MapReduceStats(
+                0, 0, 0, 0, 0, 4, kernel_folds_rowsum=rowsum,
+                kernel_folds_onehot=onehot))
+
+        shared = report(3, 0)
+        ctx = types.SimpleNamespace(reports=[shared, shared, report(2, 5),
+                                             None])
+        assert read(ctx) == pytest.approx(50.0)
+        assert read(types.SimpleNamespace(
+            reports=[report(4, 0)])) == 100.0
+        assert read(types.SimpleNamespace(reports=[report(0, 0)])) is None
+        older = types.SimpleNamespace(mapreduce=types.SimpleNamespace(
+            local_rows_read=10))
+        assert read(types.SimpleNamespace(reports=[older])) is None

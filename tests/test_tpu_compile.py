@@ -70,8 +70,8 @@ def _spec(shape, dtype, sharding):
 @pytest.mark.parametrize("groups", [1, SMOKE_GROUPS])
 def test_fused_fold_at_full_width_block(one_chip, groups):
     """The op on the committed block: the kernel reads it in place, so
-    the only temp is the sublane-padded accumulator pool — no relayout
-    or pad copy of the block."""
+    the only temp is the accumulator pool (sublane-padded when grouped)
+    — no relayout or pad copy of the block."""
     R = BLOCK_ROWS
     args = (_spec((R, FEATURES), jnp.float32, one_chip),
             _spec((R,), jnp.bool_, one_chip),
@@ -119,6 +119,50 @@ def test_engine_fold_kernel_keeps_the_name_the_benchmark_reads(one_chip):
     ops = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
     assert any(KERNEL_RE.match(op) for op in ops), [
         op[:80] for op in ops if "custom-call" in op]
+
+
+def test_engine_ungrouped_fold_writes_an_unpadded_pool(one_chip):
+    """The benchmark's fold (a masked fused mean + variance, no group-by)
+    takes the kernel's row-sum schedule: its pool is one [1, F] row a
+    power, so the kernel writes 2·F·4 bytes plus its count, and the
+    engine's executable needs no padded [8, F] pool as temp.  Its output
+    is the partial in the volume's tiled device layout, whose minor dims
+    (182, 218) pad to (184, 256): 18.7% above the logical bytes."""
+    R = BLOCK_ROWS
+    pool = 2 * FEATURES * 4
+    op = jax.jit(lambda x, m: fused_fold(x, m, names=NAMES)).lower(
+        _spec((R, FEATURES), jnp.float32, one_chip),
+        _spec((R,), jnp.bool_, one_chip)).compile().memory_analysis()
+    assert op.output_size_in_bytes <= pool + 4096, op
+    assert op.temp_size_in_bytes < 10**8, op
+    engine = MapReduceEngine(make_mesh((1,), ("data",)))
+    program = FusedProgram((MeanProgram(), VarianceProgram()))
+    fold = engine._pallas_fold_fn(program, R, MNI_SHAPE, jnp.float32,
+                                  masked=True)
+    mem = jax.jit(fold).lower(
+        _spec((R, FEATURES), jnp.float32, one_chip),
+        _spec((R,), jnp.bool_, one_chip)).compile().memory_analysis()
+    assert mem.output_size_in_bytes <= 1.19 * pool + 4096, mem
+    assert mem.temp_size_in_bytes < 10**8, mem
+
+
+@pytest.mark.parametrize("rows,dtype,names", [
+    (BLOCK_ROWS, jnp.float32, ACC_ORDER),
+    (BLOCK_ROWS, jnp.bfloat16, ACC_ORDER),
+    (1, jnp.float32, NAMES),
+], ids=["f32-all-powers", "bf16-all-powers", "one-row"])
+def test_rowsum_schedule_fits_vmem_at_full_width(one_chip, rows, dtype,
+                                                 names):
+    """The row-sum schedule's byte-sized tiles fit the default scoped
+    VMEM for every accumulator set, packed dtypes and a one-row block
+    (whose tile pads to a whole sublane tile)."""
+    arg, temp = _compile(
+        lambda x, g, m: fused_fold_pallas(x, g, m, names, 1),
+        _spec((rows, FEATURES), dtype, one_chip),
+        _spec((rows,), jnp.int32, one_chip),
+        _spec((rows,), jnp.float32, one_chip))
+    assert temp == 0, temp
+    assert arg + temp < HBM_BYTES
 
 
 @pytest.mark.parametrize("program", [
