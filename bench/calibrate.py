@@ -7,12 +7,14 @@ For each seed, in one process: the cell's whole run path (cohort, set-up,
 a short window at the cell's own rate, the float64 reference over the
 sampled answers) gives the program's readings; then the control, the
 reference computed on the chip one precision step below the
-configuration's (``reference.control_stats``: ``Precision.HIGH`` for the
-fused fold's ``HIGHEST``), is compared with the same float64 reference
+configuration's float32 (``reference.control_stats``: the values in
+bfloat16, summed in float32), is compared with the same float64 reference
 over the same sample.  Each seed prints one JSON line; the last line
 holds the largest program reading and the smallest control reading of
 each compared number.  ``bench/run.py`` never runs the control.  Needs a
-TPU.
+TPU.  The process's host memory grows with each seed (a peak of 32.5 GiB
+after three 2 mm seeds on a 40 GiB TPU v5e host), so give each process
+two or three seeds.
 """
 
 import time

@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import os
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -64,33 +64,70 @@ def layout(cfg: Dict) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     return ages, sexes, sizes
 
 
+def _draws(cfg: Dict, seed: int, shape: Optional[Sequence[int]]):
+    """What ``seed`` fixes: the volume shape, the permuted ages and sexes,
+    the sizes, the row chunks' bounds and each chunk's seed."""
+    shape = tuple(shape) if shape is not None else volume_shape(cfg)
+    ages, sexes, sizes = layout(cfg)
+    n = len(ages)
+    order_seq, data_seq = np.random.SeedSequence(int(seed)).spawn(2)
+    order = np.random.default_rng(order_seq).permutation(n)
+    bounds = np.linspace(0, n, min(CHUNKS, n) + 1).astype(int)
+    return (shape, ages[order], sexes[order], sizes, bounds,
+            data_seq.spawn(len(bounds) - 1))
+
+
+def _fill(out: np.ndarray, child, ages: np.ndarray) -> None:
+    """Draw one chunk's volumes into ``out`` (its rows' ``ages``)."""
+    np.random.default_rng(child).standard_normal(dtype=np.float32, out=out)
+    out += (ages / np.float32(100.0)).reshape((len(out),)
+                                              + (1,) * (out.ndim - 1))
+
+
+def _workers(threads: Optional[int]) -> int:
+    return threads or min(16, os.cpu_count() or 1)
+
+
 def build(cfg: Dict, seed: int, shape: Optional[Sequence[int]] = None,
           threads: Optional[int] = None) -> Cohort:
     """Draw the cohort for ``seed``; ``shape`` overrides the volume shape
     (the CPU tests' tiny volumes)."""
-    shape = tuple(shape) if shape is not None else volume_shape(cfg)
-    ages, sexes, sizes = layout(cfg)
+    shape, ages, sexes, sizes, bounds, children = _draws(cfg, seed, shape)
     n = len(ages)
-    root = np.random.SeedSequence(int(seed))
-    order_seq, data_seq = root.spawn(2)
-    order = np.random.default_rng(order_seq).permutation(n)
-    ages, sexes = ages[order], sexes[order]
     data = np.empty((n,) + shape, np.float32)
-    bounds = np.linspace(0, n, min(CHUNKS, n) + 1).astype(int)
-    children = data_seq.spawn(len(bounds) - 1)
-    bump = (ages / np.float32(100.0)).reshape((n,) + (1,) * len(shape))
 
     def fill(i: int) -> None:
         lo, hi = bounds[i], bounds[i + 1]
-        rng = np.random.default_rng(children[i])
-        rng.standard_normal(dtype=np.float32, out=data[lo:hi])
-        data[lo:hi] += bump[lo:hi]
+        _fill(data[lo:hi], children[i], ages[lo:hi])
 
-    workers = threads or min(16, os.cpu_count() or 1)
-    with ThreadPoolExecutor(workers) as pool:
-        list(pool.map(fill, range(len(bounds) - 1)))
+    with ThreadPoolExecutor(_workers(threads)) as pool:
+        list(pool.map(fill, range(len(children))))
     keys = [f"sub{i:06d}" for i in range(n)]
     return Cohort(keys, ages, sexes, sizes, data)
+
+
+def volume_blocks(cfg: Dict, seed: int,
+                  shape: Optional[Sequence[int]] = None,
+                  threads: Optional[int] = None
+                  ) -> Iterator[Tuple[int, np.ndarray]]:
+    """The cohort's volumes for ``seed`` as ``(first_row, rows)`` blocks in
+    row order, the same values as :func:`build` draws, one pool's width of
+    chunks at a time: the reference reads them without holding the whole
+    cohort."""
+    shape, ages, _, _, bounds, children = _draws(cfg, seed, shape)
+    width = _workers(threads)
+    with ThreadPoolExecutor(width) as pool:
+        for first in range(0, len(children), width):
+            chunks = range(first, min(first + width, len(children)))
+            lo, hi = bounds[chunks[0]], bounds[chunks[-1] + 1]
+            block = np.empty((hi - lo,) + shape, np.float32)
+
+            def fill(i: int) -> None:
+                a, b = bounds[i], bounds[i + 1]
+                _fill(block[a - lo:b - lo], children[i], ages[a:b])
+
+            list(pool.map(fill, chunks))
+            yield int(lo), block
 
 
 def table_of(cohort: Cohort, cfg: Dict):

@@ -25,6 +25,7 @@ import json
 import math
 import os
 import queue
+import resource
 import shutil
 import sys
 import tempfile
@@ -52,6 +53,25 @@ FETCH_THREADS = 2
 
 def log(msg: str) -> None:
     print(f"bench: {msg}", file=sys.stderr, flush=True)
+
+
+def host_memory(point: str, trace_dir: Optional[str] = None) -> None:
+    """Log the process's peak host RSS (``ru_maxrss``, KiB on Linux) and
+    its RSS now at one point of a run, with the trace file's size where
+    there is one.  Not a metric: it shows where a cell that outgrows the
+    host spends its memory."""
+    peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    msg = f"host memory {point}: peak RSS {peak / 2**30:.3f} GiB"
+    try:
+        with open("/proc/self/statm") as f:
+            now = int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+        msg += f", RSS now {now / 2**30:.3f} GiB"
+    except OSError:
+        pass
+    if trace_dir is not None:
+        size = os.path.getsize(tracefile.find_xplane(trace_dir))
+        msg += f", trace file {size / 1e6:.1f} MB"
+    log(msg)
 
 
 def load_json(path: str) -> Dict:
@@ -249,6 +269,11 @@ class Run:
         """The cohort's volumes, drawn again from the seed."""
         return cohort_mod.build(self.cell.config, self.seed, self.shape).data
 
+    def volume_blocks(self):
+        """The same volumes, drawn again a block of rows at a time."""
+        return cohort_mod.volume_blocks(self.cell.config, self.seed,
+                                        self.shape)
+
     def _answer(self, plan):
         import jax
 
@@ -264,7 +289,9 @@ class Run:
                ) -> Dict[str, Any]:
         """Offer ``queries`` open loop for ``seconds``; wait for their
         answers (up to ``DRAIN_S`` past the close).  Returns latencies,
-        lateness, the kept answers and the counters' deltas."""
+        lateness, the kept answers, the in-flight intervals and the
+        counters' deltas.  With ``trace_dir`` the window is traced into
+        that directory; the caller reduces the trace."""
         import jax
         from jax.profiler import TraceAnnotation
 
@@ -350,10 +377,9 @@ class Run:
         window_span.__exit__(None, None, None)
         inflight = [(q.t_due + late[i], t_done[i] - t0)
                     for i, q in enumerate(queries) if t_done[i] < math.inf]
-        trace = None
         if trace_dir is not None:
             jax.profiler.stop_trace()
-            trace = tracefile.reduce_trace(trace_dir, inflight)
+            host_memory("after stop_trace", trace_dir)
         for _ in fetchers:
             fetch_q.put(None)
         for th in fetchers:
@@ -364,8 +390,7 @@ class Run:
             "t0": t0, "seconds": seconds, "latency_s": lat,
             "late_s": late, "t_done": t_done, "reports": reports,
             "kept": kept, "errors": errors, "unresolved": n - got,
-            "drain_s": t_last - (t0 + seconds), "trace": trace,
-            "inflight": inflight,
+            "drain_s": t_last - (t0 + seconds), "inflight": inflight,
             "fe": _delta(fe.stats.snapshot(), fe_before),
             "session": _delta(session.metrics.snapshot(), sm_before),
             "compiles": session.engine.compile_count - compiles_before,
@@ -410,12 +435,14 @@ def end_to_end(out: Dict[str, Any], setup_s: float,
     }
 
 
-def metric_context(run: Run, out: Dict[str, Any], peaks: Dict) -> Any:
+def metric_context(run: Run, out: Dict[str, Any], peaks: Dict,
+                   trace: Optional[Dict]) -> Any:
     answered = sum(1 for t in out["t_done"] if math.isfinite(t))
     return types.SimpleNamespace(
         fe=out["fe"], session=out["session"], compiles=out["compiles"],
         device_bytes=out["device_bytes"], reports=out["reports"],
-        trace=out["trace"], answered=answered, peaks=peaks,
+        trace=trace, answered=answered, peaks=peaks,
+        latency_s=out["latency_s"],
         features=run.features, itemsize=4, row_nbytes=run.row_nbytes,
         programs=tuple(run.cell.mix["programs"]), work=work)
 
@@ -445,6 +472,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"{seconds:g} s at {cell.rate:g}/s; set-up {setup_s:.3f} s "
         + json.dumps({k: round(v, 3) for k, v in run.parts.items()}))
     log("layout " + json.dumps(run.layout))
+    host_memory("at the end of set-up")
     out = run.window(queries, seconds, keep=picked, trace_dir=trace_dir)
     peak = peak_bytes(devices)
     late = sorted(out["late_s"])
@@ -455,12 +483,20 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
     for i, e in list(out["errors"].items())[:5]:
         log(f"query {i} failed: {e}")
     log(run.session.describe().replace("\n", "\n  "))
-    ctx = metric_context(run, out, peaks)
     run.close()
+    host_memory("after run.close()")
+    tr = None
+    if trace:
+        # read once the host table and the session are freed
+        tr = tracefile.reduce_trace(trace_dir, out["inflight"])
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        host_memory("after reduce_trace")
+    ctx = metric_context(run, out, peaks, tr)
 
     t = time.perf_counter()
     answers = [out["kept"].get(i) for i in picked]
-    count, mean, var = reference.float64_stats(run.volumes(), masks[picked])
+    count, mean, var = reference.float64_stats_of_blocks(
+        run.volume_blocks(), masks[picked])
     read = reference.readings(answers, count, mean, var)
     ref_s = time.perf_counter() - t
     failed = len(out["errors"]) + out["unresolved"]
@@ -473,15 +509,15 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, *,
             v = load_metric(m["name"])(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-        shutil.rmtree(trace_dir, ignore_errors=True)
     else:
-        metrics = end_to_end(out, setup_s, peak)
+        names = {m["name"] for m in cell.metrics("end_to_end")}
+        metrics = {k: v for k, v in end_to_end(out, setup_s, peak).items()
+                   if k in names}
     device = {"platform": dev0.platform, "kind": dev0.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": len(queries),
               "failed": failed, "metrics": metrics, "device": device}
     if trace:
-        tr = out["trace"]
         device["busy_s"] = tr["busy_s"]
         device["window_s"] = tr["window_s"]
         result["breakdown"] = {"device_ops": tr["device_ops"],

@@ -6,16 +6,17 @@ and population variance of the rows its filter selects, summed over the
 benchmark's own copy of the volumes (never the system's table).  One
 pass over the cohort serves every compared answer: a thread pool splits
 the voxels, each slice is converted to float64 once, and each answer
-sums the rows it selects.
+sums the rows it selects.  The run draws the volumes again block by block
+(``cohort.volume_blocks``), so the pass never holds the whole cohort.
 
-Control: the same sums computed on the accelerator in the precision one
-step below the configuration's, which is what a later change would be
-tempted to take: the fused fold contracts at ``Precision.HIGHEST``, so
-the control contracts at ``Precision.HIGH`` (three bfloat16 passes).  For
-0/1 selection weights that is exactly ``w @ hi + w @ lo`` with ``hi`` the
-bfloat16 rounding of each value and ``lo`` the bfloat16 rounding of the
-rest, accumulated in float32; it is written out so (the products are
-exact at ``HIGHEST``), which gives the same numbers on every backend.
+Control: the same sums computed on the accelerator one precision step
+below the configurations', the step a later change would be tempted to
+take.  The configurations fold in float32 (the fused kernel's row sum
+adds float32 on the VPU), so the control takes each value in bfloat16,
+as a fold streaming bfloat16 blocks would, and sums in float32: ``w @ b``
+and ``w @ (b * b)`` with ``b`` the bfloat16 rounding of each value.  The
+products with 0/1 weights are exact at ``Precision.HIGHEST``, so it gives
+the same numbers on every backend.
 
 An empty selection answers the monoid identity: count 0, mean 0,
 variance 0.
@@ -25,7 +26,7 @@ from __future__ import annotations
 
 import os
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -42,31 +43,44 @@ def float64_stats(data: np.ndarray, masks: np.ndarray,
                   threads: Optional[int] = None
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """``(count [K], mean [K, F], var [K, F])`` in float64 for the ``K``
-    selections ``masks [K, N]`` over ``data [N, ...]``: each slice of
-    voxels is converted to float64 once, and each selection sums its own
+    selections ``masks [K, N]`` over ``data [N, ...]``."""
+    return float64_stats_of_blocks([(0, data)], masks, threads)
+
+
+def float64_stats_of_blocks(blocks: Iterable[Tuple[int, np.ndarray]],
+                            masks: np.ndarray,
+                            threads: Optional[int] = None
+                            ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """:func:`float64_stats` over the volumes given as ``(first_row,
+    rows)`` blocks that cover them in order: each slice of a block's
+    voxels is converted to float64 once, and each selection adds its own
     rows of it."""
-    n_rows = data.shape[0]
-    x = data.reshape(n_rows, -1)
-    feats = x.shape[1]
-    rows = [np.flatnonzero(m) for m in masks]
-    used = np.unique(np.concatenate(rows + [np.zeros(0, int)]))
-    pos = np.searchsorted(used, np.arange(n_rows))
-    local = [pos[r] for r in rows]
+    masks = np.asarray(masks, bool)
     count = masks.sum(axis=1).astype(np.float64)
-    s1 = np.zeros((len(rows), feats), np.float64)
-    s2 = np.zeros((len(rows), feats), np.float64)
-
-    def one(lo: int) -> None:
-        hi = min(lo + SLICE, feats)
-        xs = x[used, lo:hi].astype(np.float64)
-        x2 = xs * xs
-        for k, r in enumerate(local):
-            if len(r):
-                s1[k, lo:hi] = xs[r].sum(axis=0)
-                s2[k, lo:hi] = x2[r].sum(axis=0)
-
+    s1 = s2 = None
     with ThreadPoolExecutor(threads or min(8, os.cpu_count() or 1)) as pool:
-        list(pool.map(one, range(0, feats, SLICE)))
+        for first, block in blocks:
+            x = block.reshape(len(block), -1)
+            feats = x.shape[1]
+            if s1 is None:
+                s1 = np.zeros((len(masks), feats), np.float64)
+                s2 = np.zeros((len(masks), feats), np.float64)
+            rows = [np.flatnonzero(m) for m in masks[:, first:first + len(x)]]
+            used = np.unique(np.concatenate(rows + [np.zeros(0, int)]))
+            pos = np.searchsorted(used, np.arange(len(x)))
+            local = [pos[r] for r in rows]
+
+            def one(lo: int) -> None:
+                hi = min(lo + SLICE, feats)
+                xs = x[used, lo:hi].astype(np.float64)
+                x2 = xs * xs
+                for k, r in enumerate(local):
+                    if len(r):
+                        s1[k, lo:hi] += xs[r].sum(axis=0)
+                        s2[k, lo:hi] += x2[r].sum(axis=0)
+
+            if len(used):
+                list(pool.map(one, range(0, feats, SLICE)))
     safe = np.maximum(count, 1.0)[:, None]
     mean = s1 / safe
     var = s2 / safe - mean * mean
@@ -76,8 +90,8 @@ def float64_stats(data: np.ndarray, masks: np.ndarray,
 def control_stats(data: np.ndarray, masks: np.ndarray,
                   rows_per_step: int = 32
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The reference's sums on the default JAX device at
-    ``Precision.HIGH``: ``(count, mean, var)`` as float32 host arrays."""
+    """The reference's sums on the default JAX device over the values
+    rounded to bfloat16: ``(count, mean, var)`` as float32 host arrays."""
     import jax
     import jax.numpy as jnp
 
@@ -85,24 +99,14 @@ def control_stats(data: np.ndarray, masks: np.ndarray,
     x_host = data.reshape(n_rows, -1)
     hp = jax.lax.Precision.HIGHEST
 
-    def split(v):
-        # bfloat16 rounding kept in float32 (reduce_precision, which the
-        # compiler does not fold away as it may a convert pair)
-        hi = jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
-        lo = jax.lax.reduce_precision(v - hi, exponent_bits=8,
-                                      mantissa_bits=7)
-        return hi, lo
-
     @jax.jit
     def step(acc, xb, wb):
         s1, s2 = acc
-        x_hi, x_lo = split(xb)
-        q_hi, q_lo = split(xb * xb)
-        s1 = s1 + jnp.dot(wb, x_hi, precision=hp) + jnp.dot(wb, x_lo,
-                                                             precision=hp)
-        s2 = s2 + jnp.dot(wb, q_hi, precision=hp) + jnp.dot(wb, q_lo,
-                                                             precision=hp)
-        return s1, s2
+        # bfloat16 rounding kept in float32 (reduce_precision, which the
+        # compiler does not fold away as it may a convert pair)
+        b = jax.lax.reduce_precision(xb, exponent_bits=8, mantissa_bits=7)
+        return (s1 + jnp.dot(wb, b, precision=hp),
+                s2 + jnp.dot(wb, b * b, precision=hp))
 
     k = masks.shape[0]
     w = masks.astype(np.float32)

@@ -12,7 +12,7 @@ from bench.test_bench_harness import CELLS, run_cell
 
 #: small volumes per configuration (the control's gaps grow with the
 #: number of voxels, so these are larger than the run-path tests' volume)
-SIZES = {"t1_mni152_1mm": (16, 16, 16)}
+SIZES = {"t1_mni152_1mm": (16, 16, 16), "t1_mni152_2mm": (16, 16, 16)}
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -57,10 +57,11 @@ def _alter_one_voxel(run):
     engine.merge_finalize = altered
 
 
+@pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("fault", [_drop_half_the_rows, _alter_one_voxel],
                          ids=["half_rows_left_out", "answer_altered"])
-def test_broken_timed_path_is_not_correct(fault):
-    res = run_cell(CELLS[0], tamper=fault)
+def test_broken_timed_path_is_not_correct(fault, name):
+    res = run_cell(name, tamper=fault)
     assert res["correct"] is False
     read = {k: c["value"] for k, c in res["checks"].items()}
-    assert not reference.within(read, harness.Cell.find(CELLS[0]).limits)
+    assert not reference.within(read, harness.Cell.find(name).limits)

@@ -120,6 +120,11 @@ def test_seed_permutes_a_fixed_multiset():
     np.testing.assert_array_equal(c1.sizes, c2.sizes)
     assert sorted(zip(c1.ages, c1.sexes)) == sorted(zip(c2.ages, c2.sexes))
     np.testing.assert_array_equal(c2.data, again.data)
+    blocks = list(cohort.volume_blocks(cell.config, BIG_SEED, TINY,
+                                       threads=3))
+    assert len(blocks) > 1 and [lo for lo, _ in blocks][0] == 0
+    np.testing.assert_array_equal(
+        np.concatenate([b for _, b in blocks]), c2.data)
     assert not np.array_equal(c1.data, c2.data)
     # every query selects the same number of rows whatever the seed
     na = sorted(traffic.mask_of(q, c1.ages, c1.sexes).sum() for q in a)
@@ -138,6 +143,12 @@ def test_reference_matches_numpy():
         np.testing.assert_allclose(mean[k], x.mean(0), rtol=1e-12)
         np.testing.assert_allclose(var[k], x.var(0), rtol=1e-9, atol=1e-12)
     assert count[2] == 0 and not mean[2].any() and not var[2].any()
+    # the same over row blocks, as a run reads the volumes
+    blocks = [(lo, data[lo:lo + 7]) for lo in range(0, 30, 7)]
+    bc, bm, bv = reference.float64_stats_of_blocks(blocks, masks, threads=2)
+    np.testing.assert_array_equal(bc, count)
+    np.testing.assert_allclose(bm, mean, rtol=1e-12, atol=1e-15)
+    np.testing.assert_allclose(bv, var, rtol=1e-9, atol=1e-12)
 
 
 def test_unknown_device_has_no_peaks():

@@ -76,3 +76,10 @@ def test_idle_gaps_are_named_by_harness_spans(recorded):
     lengths = [s for _, s in tr["idle_gaps"]]
     assert lengths == sorted(lengths, reverse=True)
     assert sum(lengths) <= tr["window_s"] - tr["busy_s"] + 1e-9
+
+
+def test_reducer_reads_the_recorded_window_exactly(recorded):
+    # the numbers stored beside the trace when it was recorded: the
+    # reducer still reads every device op and span it read then
+    window, _, tr = recorded
+    assert tr == window["trace"]
