@@ -109,7 +109,9 @@ from repro.core.faults import (
     RetryPolicy,
     TransientFaultError,
 )
-from repro.core.mapreduce import MapReduceEngine, MapReduceProgram, MapReduceStats
+from repro.core.mapreduce import (
+    MapReduceEngine, MapReduceProgram, MapReduceStats, MergeRecord,
+)
 from repro.core.placement import Placement
 from repro.core.plan import GridQuery, prefix_range
 from repro.core.query import Predicate, QueryStats, indexed_query
@@ -391,6 +393,8 @@ class _ColumnOutcome:
     partials_reused: int
     rows_folded: int
     mr: MapReduceStats
+    folds_per_owner: Tuple[int, ...] = ()
+    cross_chip_bytes: int = 0
 
 
 class GridSession:
@@ -1129,7 +1133,11 @@ class GridSession:
             merge_path=_combine_paths(o.merge_path for o in outcomes),
             partials_total=sum(o.partials_total for o in outcomes),
             partials_reused=sum(o.partials_reused for o in outcomes),
-            rows_folded=sum(o.rows_folded for o in outcomes))
+            rows_folded=sum(o.rows_folded for o in outcomes),
+            folds_per_owner=tuple(
+                sum(c) for c in zip(*(o.folds_per_owner for o in outcomes
+                                      if o.folds_per_owner))),
+            cross_chip_bytes=sum(o.cross_chip_bytes for o in outcomes))
         mr = MapReduceStats(
             local_rows_read=sum(o.mr.local_rows_read for o in outcomes),
             local_bytes_read=sum(o.mr.local_bytes_read for o in outcomes),
@@ -1312,6 +1320,7 @@ class GridSession:
                                      self._put_block)
         partials: List[Any] = []
         owners: List[Optional[int]] = []
+        per_owner = [0] * len(self.placement.nodes)
         p_total = p_reused = rows_folded = local_rows = chunks = 0
         kernel_folds = {"rowsum": 0, "onehot": 0}
         rounds: Dict[Optional[int], int] = {}
@@ -1358,12 +1367,15 @@ class GridSession:
                     if schedule:
                         kernel_folds[schedule] += 1
                     rounds[w.owner] = rounds.get(w.owner, 0) + c
+                    if w.owner is not None:
+                        per_owner[w.owner] += 1
             partials.append(partial)
             owners.append(w.owner)
+        merged = MergeRecord()
         with spans.span("merge.dispatch"):
-            result = self.engine.merge_finalize(program, partials,
-                                                spec.shape, spec.dtype,
-                                                owners=owners)
+            result = self.engine.merge_finalize(
+                program, partials, spec.shape, spec.dtype, owners=owners,
+                slots=self._merge_slots(), record=merged)
         self._results.put(result_key, _ResultEntry(
             result=result, partials_total=p_total, blocks_total=acct.total,
             region_ids=frozenset(w.region.rid for w in work),
@@ -1393,9 +1405,20 @@ class GridSession:
             kernel_folds_onehot=kernel_folds["onehot"])
         return _ColumnOutcome(
             result=result, hit=False, gather_path="blocks",
-            merge_path=self.engine.last_merge_path, acct=acct,
+            merge_path=merged.path, acct=acct,
             partials_total=p_total, partials_reused=p_reused,
-            rows_folded=rows_folded, mr=mr)
+            rows_folded=rows_folded, mr=mr,
+            folds_per_owner=tuple(per_owner),
+            cross_chip_bytes=merged.cross_chip_bytes)
+
+    def _merge_slots(self) -> int:
+        """The most regions one node owns under the current placement: the
+        width of the tree merge's owner-local sums, fixed until the
+        placement changes."""
+        owned: Dict[Any, int] = {}
+        for node in self.placement.alloc.values():
+            owned[node] = owned.get(node, 0) + 1
+        return max(owned.values(), default=1)
 
     def _fold_cold(
         self, program: MapReduceProgram, eta: int,
@@ -1733,7 +1756,9 @@ class GridSession:
             f"eta={self.default_eta}, imbalance={self.imbalance():.3f})",
             self.placement.describe(),
             f"  results: {m.plan_hits} hits / {m.plan_misses} misses; "
-            f"engine compiles: {self.engine.compile_count}",
+            f"engine compiles: {self.engine.compile_count}; merges: "
+            f"{self.engine.merge_path_counts['tree']} tree, "
+            f"{self.engine.merge_path_counts['funnel']} funnel",
             f"  folds: {m.partials_folded} block partials folded "
             f"({m.rows_folded} rows), {m.partials_reused} reused, "
             f"{m.compact_scans} compact one-shots; on the fused kernel "

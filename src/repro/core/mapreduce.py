@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import spans
 from repro.core.blockstore import LRUCache
 from repro.utils import shard_map_compat
 
@@ -197,6 +198,13 @@ def _checked_merge_ops(program: MapReduceProgram,
     return ops
 
 
+def _off_device_bytes(partial: PyTree, dev) -> int:
+    """Bytes of ``partial``'s device leaves held by a chip other than
+    ``dev`` (host leaves cross from the host, not from a chip)."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(partial)
+               if isinstance(x, jax.Array) and dev not in x.devices())
+
+
 def _combine_leafwise(partial_like: PyTree, ops: Optional[List[str]],
                       sum_fn: Callable[[Any], Any],
                       max_fn: Callable[[Any], Any]) -> PyTree:
@@ -208,6 +216,17 @@ def _combine_leafwise(partial_like: PyTree, ops: Optional[List[str]],
     out = [max_fn(leaf) if op == "max" else sum_fn(leaf)
            for leaf, op in zip(leaves, ops)]
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@dataclasses.dataclass
+class MergeRecord:
+    """What one :meth:`MapReduceEngine.merge_finalize` did, filled in for
+    the caller that passed it: the physical reduce it took (``"tree"`` /
+    ``"funnel"``) and the partial bytes ``device_put`` moved from one chip
+    to another before the reduce (the all-reduce itself not counted)."""
+
+    path: str = ""
+    cross_chip_bytes: int = 0
 
 
 @dataclasses.dataclass
@@ -295,27 +314,18 @@ class MapReduceEngine:
         # dispatch of an already-built executable stays lock-free
         self._build_lock = threading.RLock()
         self._count_lock = threading.Lock()
-        # the last merge path is per-thread: concurrent queries must each
-        # read the path of THEIR merge, not whichever finished last
-        self._tls = threading.local()
+        # launches of programs that span the mesh (a psum) are serialized:
+        # two queries' collectives enqueued in different orders on two
+        # chips would each wait for the other on the interconnect
+        self._collective_lock = threading.Lock()
+        # per-device identity partials padding the tree merge's presums
+        self._identities = LRUCache(16)
         # the mesh's data-axis devices, in shard order — available only when
         # the mesh is exactly the 1-D data axis (same condition the session
         # uses for per-shard block placement); None disables the tree reduce
         devs = np.asarray(mesh.devices).flat
         self._axis_devices = (list(devs)
                               if mesh.axis_names == (data_axis,) else None)
-
-    @property
-    def last_merge_path(self) -> str:
-        """Which physical reduce the CALLING THREAD's last
-        :meth:`merge_finalize` took ("tree" / "funnel"; "" before any
-        merge on this thread).  Thread-local so concurrent queries each
-        observe their own merge, not whichever finished last."""
-        return getattr(self._tls, "last_merge_path", "")
-
-    @last_merge_path.setter
-    def last_merge_path(self, value: str) -> None:
-        self._tls.last_merge_path = value
 
     # ------------------------------------------------------------------
 
@@ -639,6 +649,8 @@ class MapReduceEngine:
         row_shape: Tuple[int, ...],
         dtype,
         owners: Optional[Sequence[Optional[int]]] = None,
+        slots: Optional[int] = None,
+        record: Optional[MergeRecord] = None,
     ) -> PyTree:
         """Reduce phase: combine the per-block partials and finalize.
 
@@ -657,18 +669,27 @@ class MapReduceEngine:
         Zero partials finalize the monoid identity (the empty-selection
         result).  Funnel executables are keyed by the partial count rounded
         up to a power of two (identity-padded), so drifting block counts
-        don't multiply compiles.
+        don't multiply compiles.  ``slots`` fixes the width of the tree's
+        owner-local sums (the most blocks one owner holds under the
+        placement), so every query's sums share one executable per device;
+        without it the width is the busiest owner's partial count rounded
+        up to a power of two.  ``record`` receives the path taken and the
+        partial bytes moved between chips; the return value is the
+        finalized result alone.
         """
+        if record is None:
+            record = MergeRecord()
         if self._tree_merge_ok(program, partials, owners):
-            self.last_merge_path = "tree"
+            record.path = "tree"
             with self._count_lock:
                 self.merge_path_counts["tree"] += 1
             return self._merge_tree(program, partials, owners,
-                                    row_shape, dtype)
-        self.last_merge_path = "funnel"
+                                    row_shape, dtype, slots, record)
+        record.path = "funnel"
         with self._count_lock:
             self.merge_path_counts["funnel"] += 1
-        return self._merge_funnel(program, partials, row_shape, dtype)
+        return self._merge_funnel(program, partials, row_shape, dtype,
+                                  record)
 
     def _tree_merge_ok(self, program, partials, owners) -> bool:
         return (self.merge_strategy == "auto"
@@ -683,10 +704,8 @@ class MapReduceEngine:
 
     def _presum_fn(self, program, count: int, row_shape, dtype):
         """One jitted per-device sum over ``count`` stacked partials — the
-        owner-local pre-merge of the tree reduce.  Keyed by the pow2-
-        bucketed partial count (identity-padded), so drifting per-owner
-        block counts share a handful of compiles instead of dispatching a
-        Python loop of adds per partial."""
+        owner-local pre-merge of the tree reduce, its leaves given a
+        leading axis of one (the owner's row of the all-reduce's input)."""
         key = ("bpresum", program.cache_key(), int(count), tuple(row_shape),
                str(dtype))
 
@@ -694,57 +713,70 @@ class MapReduceEngine:
             def presum(*ps):
                 ops = _checked_merge_ops(program, ps[0])
                 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *ps)
-                return _combine_leafwise(stacked, ops,
-                                         lambda s: s.sum(axis=0),
-                                         lambda s: s.max(axis=0))
+                return _combine_leafwise(
+                    stacked, ops, lambda s: s.sum(axis=0, keepdims=True),
+                    lambda s: s.max(axis=0, keepdims=True))
 
             return jax.jit(presum)
 
         return self._get_or_build(key, build)
 
-    def _merge_tree(self, program, partials, owners, row_shape, dtype):
-        """psum-over-mesh reduce: owner-local pre-merge, one all-reduce."""
+    def _identity(self, program, row_shape, dtype, dev) -> PyTree:
+        """The program's identity partial, committed to ``dev`` once."""
+        key = (program.cache_key(), tuple(row_shape), str(dtype), dev.id)
+        ident = self._identities.get(key)
+        if ident is None:
+            ident = jax.device_put(program.zero(tuple(row_shape), dtype), dev)
+            self._identities.put(key, ident)
+        return ident
+
+    def _merge_tree(self, program, partials, owners, row_shape, dtype,
+                    slots: Optional[int], record: MergeRecord):
+        """psum-over-mesh reduce: owner-local pre-merge, one all-reduce.
+
+        Every owner sums a stack of one width, its partials padded with
+        identity partials, so a query's presums never compile however its
+        selected blocks fall over the owners."""
         D = len(self._axis_devices)
         by_owner: List[List[PyTree]] = [[] for _ in range(D)]
         for p, o in zip(partials, owners):
             by_owner[o].append(p)
-        identity = None
+        busiest = max(len(ps) for ps in by_owner)
+        width = (slots if slots is not None and busiest <= slots
+                 else self._next_pow2(busiest))
+        presum = self._presum_fn(program, width, row_shape, dtype)
 
-        def ident(dev):
-            nonlocal identity
-            if identity is None:
-                identity = program.zero(tuple(row_shape), dtype)
-            return jax.device_put(identity, dev)
-
-        shards = []
-        for d, ps in enumerate(by_owner):
-            dev = self._axis_devices[d]
-            if not ps:
-                acc = ident(dev)
-            elif len(ps) == 1:
+        with spans.span("merge.presum"):
+            shards = []
+            for d, ps in enumerate(by_owner):
+                dev = self._axis_devices[d]
                 # partials folded this execution already live on device d;
                 # cached partials from a pre-rebalance owner re-home here
                 # (tiny — a partial, never a payload block)
-                acc = jax.device_put(ps[0], dev)
-            else:
-                # one jitted stack+sum per owner (tree path ⇒ additive),
-                # identity-padded to the pow2 count bucket
+                record.cross_chip_bytes += sum(_off_device_bytes(p, dev)
+                                               for p in ps)
                 moved = [jax.device_put(p, dev) for p in ps]
-                bucket = self._next_pow2(len(moved))
-                moved.extend([ident(dev)] * (bucket - len(moved)))
-                acc = self._presum_fn(program, bucket, row_shape,
-                                      dtype)(*moved)
-            shards.append(jax.tree.map(lambda x: x[None], acc))
+                moved.extend([self._identity(program, row_shape, dtype, dev)]
+                             * (width - len(moved)))
+                shards.append(presum(*moved))
 
-        sharding = NamedSharding(self.mesh, P(self.data_axis))
+        with spans.span("merge.allreduce"):
+            sharding = NamedSharding(self.mesh, P(self.data_axis))
 
-        def assemble(*leaves):
-            shape = (D,) + tuple(leaves[0].shape[1:])
-            return jax.make_array_from_single_device_arrays(
-                shape, sharding, list(leaves))
+            def assemble(*leaves):
+                shape = (D,) + tuple(leaves[0].shape[1:])
+                return jax.make_array_from_single_device_arrays(
+                    shape, sharding, list(leaves))
 
-        stacked = jax.tree.map(assemble, *shards)
+            stacked = jax.tree.map(assemble, *shards)
+            fn = self._allreduce_fn(program, row_shape, dtype)
+            with self._collective_lock:
+                return fn(stacked)
 
+    def _allreduce_fn(self, program, row_shape, dtype):
+        """The tree merge's one program over the mesh: the owners' sums,
+        stacked ``[D, ...]`` along the data axis, joined by one ``psum``
+        (``pmax`` for max leaves) and finalized on every device."""
         key = ("btree", program.cache_key(), tuple(row_shape), str(dtype))
 
         def build():
@@ -760,9 +792,10 @@ class MapReduceEngine:
                 out_specs=P(), check=False)
             return jax.jit(lambda t: program.finalize(reduce_fn(t)))
 
-        return self._get_or_build(key, build)(stacked)
+        return self._get_or_build(key, build)
 
-    def _merge_funnel(self, program, partials, row_shape, dtype):
+    def _merge_funnel(self, program, partials, row_shape, dtype,
+                      record: MergeRecord):
         """Single-device reduce: partials meet on the merge device and one
         jitted merge+finalize combines them (count bucketed to a power of
         two with identity partials, so the executable key space stays
@@ -799,6 +832,9 @@ class MapReduceEngine:
 
         fn = self._get_or_build(key, build)
         dev = self._merge_device
+        if self.mesh.devices.size > 1:
+            record.cross_chip_bytes += sum(_off_device_bytes(p, dev)
+                                           for p in partials)
         moved = [jax.device_put(p, dev) for p in partials]
         if bucket > n:
             identity = jax.device_put(
@@ -881,7 +917,8 @@ class MapReduceEngine:
         key = (program.cache_key(), row_shape, str(dtype), chunk_size, C)
         fn = self._get_or_build(
             key, lambda: self._build(program, row_shape, dtype, chunk_size))
-        result = fn(values, mask)
+        with self._collective_lock:
+            result = fn(values, mask)
 
         # --- byte accounting (host-side; mask is tiny) -------------------
         mask_np = np.asarray(jax.device_get(mask))

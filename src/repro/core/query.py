@@ -80,6 +80,14 @@ class QueryStats:
     # jitted merge); "" when no merge ran (result-cache hit, compact path,
     # retrieve).
     merge_path: str = ""
+    # block folds dispatched to each owner device, in mesh order (() when
+    # no block folded): the busiest owner sets the answer's time, so an
+    # uneven spread is the placement's cost
+    folds_per_owner: Tuple[int, ...] = ()
+    # partial bytes device_put moved from one chip to another outside the
+    # all-reduce (blocks commit from their host copy to their owner and
+    # never move between chips); data colocation keeps this 0
+    cross_chip_bytes: int = 0
 
     @property
     def total_bytes_scanned(self) -> int:

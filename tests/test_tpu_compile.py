@@ -20,7 +20,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
 
 from bench.tracefile import KERNEL_RE
 from repro.core.mapreduce import MapReduceEngine
@@ -39,7 +40,8 @@ NAMES = ("count", "s1", "s2")   # the fused mean + variance pool
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def host_2x2():
+    """The four chips of a described v5e 2x2 host."""
     # skip only where the TPU compiler is not installed; any other failure
     # to describe the chip (a held library lock, a compiler regression)
     # fails the test
@@ -52,8 +54,13 @@ def one_chip():
     # cache without the chip: keep them out of it
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(host_2x2):
+    return SingleDeviceSharding(host_2x2[0])
 
 
 def _compile(fn, *args):
@@ -200,3 +207,33 @@ def test_fused_fold_pallas_at_vmem_group_limit(one_chip, names):
         _spec((R,), jnp.int32, one_chip),
         _spec((R,), jnp.float32, one_chip))
     assert arg + temp < HBM_BYTES
+
+
+def test_tree_merge_at_full_width_on_a_2x2_host(host_2x2):
+    """The four-chip cell's merge at the full 1 mm width: a chip's 8-wide
+    owner-local sum reads its partials in place (no temp), and the sums
+    meet in one all-reduce over the 2x2 mesh whose only output is the
+    replicated answer."""
+    engine = MapReduceEngine(Mesh(np.array(host_2x2), ("data",)))
+    program = FusedProgram((MeanProgram(), VarianceProgram()))
+    zero = jax.eval_shape(lambda: program.zero(MNI_SHAPE, jnp.float32))
+    owner = SingleDeviceSharding(host_2x2[1])
+    partial = jax.tree.map(lambda a: _spec(a.shape, a.dtype, owner), zero)
+    presum = engine._presum_fn(program, 8, MNI_SHAPE, jnp.float32)
+    mem = presum.lower(*[partial] * 8).compile().memory_analysis()
+    assert mem.temp_size_in_bytes == 0, mem
+    assert mem.argument_size_in_bytes >= 8 * 2 * FEATURES * 4, mem
+    assert mem.output_size_in_bytes < 1.19 * 2 * FEATURES * 4 + 4096, mem
+
+    rows = NamedSharding(engine.mesh, PartitionSpec("data"))
+    stacked = jax.tree.map(
+        lambda a: _spec((len(host_2x2),) + a.shape, a.dtype, rows), zero)
+    compiled = engine._allreduce_fn(program, MNI_SHAPE,
+                                    jnp.float32).lower(stacked).compile()
+    text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if " all-reduce(" in ln]) == 1, text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0, mem
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        < HBM_BYTES // 10, mem
